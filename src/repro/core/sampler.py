@@ -45,20 +45,13 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
-# Module-scope (NOT inside the traced loop body, where a failure would be
-# masked until first trace) -- but guarded: only fused plans need Pallas, so
-# an environment without it can still import and run every unfused plan.
-try:
-    from ..kernels.ops import fused_ab_step as _fused_ab_step
-except ImportError as _e:  # pragma: no cover - depends on jax build
-    _fused_ab_step = None
-    _FUSED_IMPORT_ERROR = _e
-
+from ..kernels.ops import fused_ab_step as _fused_ab_step
 from .plan import SolverPlan
 
 Array = jax.Array
@@ -284,8 +277,39 @@ def _noise_like(sub, x, stacked: bool):
     return jax.random.normal(sub, x.shape, x.dtype)
 
 
+def _fused_rows(xf, hf, psi_r, C_r, s_r, n_r, E_r, mesh):
+    """The fused kernel over ``(R, M, D)`` rows, per shard under a mesh.
+
+    A Mosaic kernel is opaque to XLA's SPMD partitioner, so under a
+    request-axis mesh the call runs inside ``shard_map`` over the data
+    axes: each device steps its own rows. The kernel computes every row
+    independently, so this is exact -- bitwise the unsharded result."""
+    ops = {"x": xf, "hist": hf, "psi": psi_r, "C": C_r}
+    if n_r is not None:
+        ops.update(s=s_r, noise=n_r)
+    if E_r is not None:
+        ops["E"] = E_r
+
+    def run(o):
+        return _fused_ab_step(o["x"], o["hist"], o["psi"], o["C"],
+                              s=o.get("s"), noise=o.get("noise"),
+                              err_coeffs=o.get("E"))
+
+    if mesh is None:
+        return run(ops)
+    from ..sharding.rules import request_axis_spec
+    specs = {name: request_axis_spec(v, mesh, 1 if name == "hist" else 0)
+             for name, v in ops.items()}
+    err_spec = specs["psi"] if E_r is not None else None   # (R,) like psi
+    # check_vma=False: the kernel's out_shape carries no varying-axes
+    # annotation; rows are independent, so every output is per-shard exact
+    return jax.shard_map(run, mesh=mesh, in_specs=(specs,),
+                         out_specs=(specs["x"], err_spec),
+                         check_vma=False)(ops)
+
+
 def _step_ab(plan: SolverPlan, k, state: SamplerState, eps_fn: EpsFn,
-             hooks: Hooks) -> SamplerState:
+             hooks: Hooks, mesh=None) -> SamplerState:
     c, stk = plan.coeffs, plan.stacked
     x, key = state.x, state.key
     if plan.stochastic:
@@ -311,10 +335,6 @@ def _step_ab(plan: SolverPlan, k, state: SamplerState, eps_fn: EpsFn,
         if "nu" in c:
             Ew = Ew * nu          # the pair difference is normalized too
     if plan.fused:
-        if _fused_ab_step is None:
-            raise ImportError("plan.fused=True requires the Pallas deis_step "
-                              "kernel, which failed to import"
-                              ) from _FUSED_IMPORT_ERROR
         # Flatten to the kernel's (R, M, D) layout. Unstacked solves run as a
         # one-row stack, so solo and stacked groups share the same per-block
         # arithmetic (the serving bitwise-vs-solo invariant). Noise draw and
@@ -336,8 +356,8 @@ def _step_ab(plan: SolverPlan, k, state: SamplerState, eps_fn: EpsFn,
             s_r = jnp.reshape(s_coef, (1,)) if s_coef is not None else None
             E_r = Ew[None] if Ew is not None else None
         n_r = noise.reshape(xf.shape) if noise is not None else None
-        out, err_raw = _fused_ab_step(xf, hf, psi_r, C_r, s=s_r, noise=n_r,
-                                      err_coeffs=E_r)
+        out, err_raw = _fused_rows(xf, hf, psi_r, C_r, s_r, n_r, E_r,
+                                   mesh if stk else None)
         x_new = out.reshape(x.shape)
         if Ew is not None:
             raw = err_raw if stk else err_raw[0]
@@ -488,6 +508,14 @@ def _step_pndm(plan: SolverPlan, k, state: SamplerState, eps_fn: EpsFn,
 _STEPPERS = {"ab": _step_ab, "rk": _step_rk, "pndm": _step_pndm}
 
 
+def _stepper(method: str, mesh):
+    """The step function for ``method``; the fused AB path needs the mesh
+    to run its kernel per shard (:func:`_fused_rows`)."""
+    if method == "ab" and mesh is not None:
+        return functools.partial(_step_ab, mesh=mesh)
+    return _STEPPERS[method]
+
+
 def step(plan: SolverPlan, k, state: SamplerState, eps_fn: EpsFn, *,
          hooks: Optional[Hooks] = None, mesh=None) -> SamplerState:
     """Advance one solver step: ``state`` at time ``ts[k]`` -> ``ts[k+1]``.
@@ -501,8 +529,8 @@ def step(plan: SolverPlan, k, state: SamplerState, eps_fn: EpsFn, *,
     stacked request axis of every state/plan leaf with a ``NamedSharding``
     before stepping -- data-parallel execution over requests. Sharding never
     changes WHAT is computed (row ``i`` is row ``i``'s solo solve, bitwise);
-    serving's AOT executors instead jit with explicit in/out shardings and
-    pass no mesh here.
+    serving's AOT executors jit with explicit in/out shardings and pass
+    their mesh here too, so a fused plan's kernel runs per shard.
     """
     plan = plan.astype(state.x.dtype)
     if jnp.ndim(k):
@@ -511,7 +539,8 @@ def step(plan: SolverPlan, k, state: SamplerState, eps_fn: EpsFn, *,
         k = jnp.minimum(jnp.asarray(k, jnp.int32), plan.n_steps - 1)
     if mesh is not None:
         plan, state = shard_state(plan, state, mesh)
-    return _STEPPERS[plan.method](plan, k, state, eps_fn, hooks or _DEFAULT_HOOKS)
+    return _stepper(plan.method, mesh)(plan, k, state, eps_fn,
+                                       hooks or _DEFAULT_HOOKS)
 
 
 def sample(plan: SolverPlan, eps_fn: EpsFn, x_T: Array,
@@ -546,7 +575,7 @@ def sample(plan: SolverPlan, eps_fn: EpsFn, x_T: Array,
     if mesh is not None:
         plan, state = shard_state(plan, state, mesh)
     n = plan.n_steps
-    stepper = _STEPPERS[plan.method]
+    stepper = _stepper(plan.method, mesh)
 
     # pndm's warmup/tail differ structurally, so it always unrolls; a tracer
     # forces the same eager loop for ab/rk so each step gets its own span.

@@ -8,15 +8,18 @@ The update is memory-bound (zero MXU work): the win over XLA's un-fused form
 is reading x and each eps exactly once from HBM instead of r+3 round trips
 for the partial sums, the noise add and the error-pair combination. VPU-
 tiled: blocks are (BLK_M, 128)-aligned in VMEM; per-row scalars (psi, C,
-s, E) ride along as one small ``(R, ncols)`` VMEM operand indexed by the
+s, E) ride along as one small ``(R, 1, ncols)`` VMEM operand indexed by the
 row grid axis, which is what lets one kernel serve a stacked serving group
-whose rows carry different solver coefficients.
+whose rows carry different solver coefficients. Every block's last two
+dimensions either tile (8, 128) or span the whole array -- the layout rule
+Mosaic enforces -- so the kernel compiles on a TPU for any row count R.
 
-The error output is an exact Linf: each block writes its partial
-``max |E . hist|`` and the caller reduces with an outer ``jnp.max`` --
-f32 max is reduction-order independent, so a row's error (and therefore
-early-exit retirement) is bitwise identical between a solo solve (R=1) and
-any stacked grouping of the same request.
+The error output is an exact Linf: each block writes its lane-wise partial
+``max |E . hist|`` as one lane-dense ``(1, BLK_D)`` row and the caller
+reduces with an outer ``jnp.max`` -- f32 max is reduction-order
+independent, so a row's error (and therefore early-exit retirement) is
+bitwise identical between a solo solve (R=1) and any stacked grouping of
+the same request.
 """
 from __future__ import annotations
 
@@ -26,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .runtime import default_interpret as _resolve_interpret
+from . import runtime
 
 BLK_M = 256
 BLK_D = 128
@@ -39,13 +42,13 @@ def default_interpret() -> bool:
     (:func:`repro.kernels.runtime.default_interpret`): Mosaic on TPU,
     Triton on GPU, interpreter on CPU only.
     """
-    return _resolve_interpret("deis_step")
+    return runtime.default_interpret("deis_step")
 
 
 def _kernel(scal_ref, *refs, r, has_noise, has_err):
-    # scal_ref: (1, ncols) f32 rows laid out [psi, C_0..C_{r-1}, s?, E_*?];
+    # scal_ref: (1, 1, ncols) f32 row laid out [psi, C_0..C_{r-1}, s?, E_*?];
     # refs: x_ref (1,BM,BD), hist_ref (r,1,BM,BD), [noise_ref (1,BM,BD)],
-    #       out_ref (1,BM,BD), [err_ref (1,1,1)]
+    #       out_ref (1,BM,BD), [err_ref (1,1,1,BD)]
     x_ref = refs[0]
     hist_ref = refs[1]
     noise_ref = refs[2] if has_noise else None
@@ -53,19 +56,19 @@ def _kernel(scal_ref, *refs, r, has_noise, has_err):
     out_ref = refs[out_idx]
     err_ref = refs[out_idx + 1] if has_err else None
 
-    acc = scal_ref[0, 0] * x_ref[0].astype(jnp.float32)
+    acc = scal_ref[0, 0, 0] * x_ref[0].astype(jnp.float32)
     for j in range(r):  # static unroll; r <= 4
-        acc += scal_ref[0, 1 + j] * hist_ref[j, 0].astype(jnp.float32)
+        acc += scal_ref[0, 0, 1 + j] * hist_ref[j, 0].astype(jnp.float32)
     if has_noise:
-        acc += scal_ref[0, 1 + r] * noise_ref[0].astype(jnp.float32)
+        acc += scal_ref[0, 0, 1 + r] * noise_ref[0].astype(jnp.float32)
     out_ref[0] = acc.astype(out_ref.dtype)
 
     if has_err:
         off = 1 + r + (1 if has_noise else 0)
-        e = scal_ref[0, off] * hist_ref[0, 0].astype(jnp.float32)
+        e = scal_ref[0, 0, off] * hist_ref[0, 0].astype(jnp.float32)
         for j in range(1, r):
-            e += scal_ref[0, off + j] * hist_ref[j, 0].astype(jnp.float32)
-        err_ref[0, 0, 0] = jnp.max(jnp.abs(e))
+            e += scal_ref[0, 0, off + j] * hist_ref[j, 0].astype(jnp.float32)
+        err_ref[0, 0] = jnp.max(jnp.abs(e), axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("has_err", "interpret"))
@@ -82,11 +85,11 @@ def _fused_ab_jit(scal, x, hist, noise, *, has_err: bool, interpret: bool):
     nbm, nbd = (m + pm) // BLK_M, (d + pd) // BLK_D
 
     in_specs = [
-        pl.BlockSpec((1, ncols), lambda g, i, j: (g, 0)),
+        pl.BlockSpec((1, 1, ncols), lambda g, i, j: (g, 0, 0)),
         pl.BlockSpec((1, BLK_M, BLK_D), lambda g, i, j: (g, i, j)),
         pl.BlockSpec((r, 1, BLK_M, BLK_D), lambda g, i, j: (0, g, i, j)),
     ]
-    operands = [scal, xp, hp]
+    operands = [scal[:, None, :], xp, hp]
     if has_noise:
         in_specs.append(pl.BlockSpec((1, BLK_M, BLK_D),
                                      lambda g, i, j: (g, i, j)))
@@ -94,8 +97,9 @@ def _fused_ab_jit(scal, x, hist, noise, *, has_err: bool, interpret: bool):
     out_specs = [pl.BlockSpec((1, BLK_M, BLK_D), lambda g, i, j: (g, i, j))]
     out_shape = [jax.ShapeDtypeStruct(xp.shape, x.dtype)]
     if has_err:
-        out_specs.append(pl.BlockSpec((1, 1, 1), lambda g, i, j: (g, i, j)))
-        out_shape.append(jax.ShapeDtypeStruct((n_rows, nbm, nbd),
+        out_specs.append(pl.BlockSpec((1, 1, 1, BLK_D),
+                                      lambda g, i, j: (g, i, 0, j)))
+        out_shape.append(jax.ShapeDtypeStruct((n_rows, nbm, 1, nbd * BLK_D),
                                               jnp.float32))
 
     res = pl.pallas_call(
@@ -108,7 +112,7 @@ def _fused_ab_jit(scal, x, hist, noise, *, has_err: bool, interpret: bool):
     )(*operands)
     out = res[0][:, :m, :d]
     # exact Linf: per-block partial maxima reduced by an order-independent max
-    err = jnp.max(res[1], axis=(1, 2)) if has_err else None
+    err = jnp.max(res[1], axis=(1, 2, 3)) if has_err else None
     return out, err
 
 

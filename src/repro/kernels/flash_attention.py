@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .runtime import default_interpret as _resolve_interpret
+from . import runtime
 
 NEG_INF = -1e30
 
@@ -35,7 +35,7 @@ def default_interpret() -> bool:
     Resolved through the shared per-kernel capability table
     (:func:`repro.kernels.runtime.default_interpret`).
     """
-    return _resolve_interpret("flash_attention")
+    return runtime.default_interpret("flash_attention")
 
 
 def _kernel(q_ref, k_ref, v_ref, out_ref, *, scale, causal, window,

@@ -2,12 +2,14 @@
 lowering, and therefore what ``interpret=None`` should resolve to.
 
 All three kernels are written against the generic Pallas API (no ``pltpu``
-scratch shapes, no cross-grid-step state carry), which lowers to Mosaic on
-TPU and Triton on GPU. Only the CPU backend has no compiled lowering and
-must fall back to the Python interpreter. The table is per kernel so that a
-future kernel with a narrower lowering (e.g. Mosaic-only constructs) can
-declare it here instead of silently interpreting everywhere, which is the
-bug class RL005 lints against.
+scratch shapes, no cross-grid-step state carry). Their Mosaic lowering is
+checked at real widths for a described TPU v5e by
+``tests/test_tpu_compile.py``; the Triton (GPU) lowering is the same
+generic Pallas but no test compiles it. Only the CPU backend has no
+compiled lowering and runs the Python interpreter. The table is per kernel
+so that a future kernel with a narrower lowering (e.g. Mosaic-only
+constructs) can declare it here instead of silently interpreting
+everywhere, which is the bug class RL005 lints against.
 """
 from __future__ import annotations
 

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .runtime import default_interpret as _resolve_interpret
+from . import runtime
 
 
 def default_interpret() -> bool:
@@ -34,28 +34,38 @@ def default_interpret() -> bool:
     Resolved through the shared per-kernel capability table
     (:func:`repro.kernels.runtime.default_interpret`).
     """
-    return _resolve_interpret("ssd_scan")
+    return runtime.default_interpret("ssd_scan")
 
 
-def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref, *,
-            n_chunks, chunk):
+def _kernel(x_ref, a_col_ref, a_row_ref, b_ref, c_ref, y_ref, state_out_ref,
+            *, n_chunks, chunk):
     p = x_ref.shape[-1]
     n = b_ref.shape[-1]
+    # causal masks over (t, u) within a chunk: u <= t, and its transpose
+    row_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = col_i <= row_i
+    tri_t = row_i <= col_i
 
     def body(cidx, h_prev):
         sl = pl.ds(cidx * chunk, chunk)
         x = x_ref[0, sl, :].astype(jnp.float32)        # (L, P)
-        a = a_ref[0, sl, 0].astype(jnp.float32)        # (L,)
         B = b_ref[0, sl, :].astype(jnp.float32)        # (L, N)
         C = c_ref[0, sl, :].astype(jnp.float32)        # (L, N)
-
-        log_a = jnp.log(jnp.maximum(a, 1e-37))
-        cum = jnp.cumsum(log_a)                        # (L,) inclusive
+        # the per-step decays arrive in both orientations, so the inclusive
+        # prefix sums come out as a column and as a row by masked
+        # reductions (no cumsum, no transpose: neither lowers to Mosaic)
+        la_col = jnp.log(jnp.maximum(
+            a_col_ref[0, sl, :].astype(jnp.float32), 1e-37))        # (L, 1)
+        la_row = jnp.log(jnp.maximum(
+            a_row_ref[0, pl.ds(cidx, 1), :].astype(jnp.float32), 1e-37))  # (1, L)
+        cum_col = jnp.sum(jnp.where(tri, la_row, 0.0), axis=1,
+                          keepdims=True)                 # (L, 1) inclusive
+        cum_row = jnp.sum(jnp.where(tri_t, la_col, 0.0), axis=0,
+                          keepdims=True)                 # (1, L) inclusive
+        total = jnp.sum(la_row, axis=1, keepdims=True)   # (1, 1)
         # within-chunk decay matrix exp(cum_t - cum_u) for u <= t
-        seg = cum[:, None] - cum[None, :]
-        tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1) <= \
-            jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-        decay = jnp.where(tri, jnp.exp(seg), 0.0)
+        decay = jnp.where(tri, jnp.exp(cum_col - cum_row), 0.0)
 
         scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
@@ -63,14 +73,13 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref, *,
         y = jax.lax.dot(w, x, preferred_element_type=jnp.float32)
 
         # inter-chunk from carried state
-        c_in = C * jnp.exp(cum)[:, None]               # (L, N)
+        c_in = C * jnp.exp(cum_col)                    # (L, N)
         y += jax.lax.dot_general(c_in, h_prev, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
 
         # state update
-        decay_to_end = jnp.exp(cum[-1] - cum)          # (L,)
-        b_out = B * decay_to_end[:, None]              # (L, N)
-        h_new = h_prev * jnp.exp(cum[-1]) + jax.lax.dot_general(
+        b_out = B * jnp.exp(total - cum_col)           # (L, N)
+        h_new = h_prev * jnp.exp(total) + jax.lax.dot_general(
             x, b_out, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         y_ref[0, sl, :] = y.astype(y_ref.dtype)
@@ -112,7 +121,9 @@ def _ssd_scan_jit(x, a, B, C, *, chunk: int, interpret: bool):
     # layouts: fold (B,H) -> G for x/a; B/C shared across heads (indexed by
     # batch only in the map)
     xt = x.transpose(0, 2, 1, 3).reshape(bb * h, sp, p)
-    at = a.transpose(0, 2, 1).reshape(bb * h, sp, 1)
+    at = a.transpose(0, 2, 1)
+    a_col = at.reshape(bb * h, sp, 1)
+    a_row = at.reshape(bb * h, n_chunks, chunk)
 
     def xa_map(g):
         return (g, 0, 0)
@@ -127,6 +138,7 @@ def _ssd_scan_jit(x, a, B, C, *, chunk: int, interpret: bool):
         in_specs=[
             pl.BlockSpec((1, sp, p), xa_map),
             pl.BlockSpec((1, sp, 1), xa_map),
+            pl.BlockSpec((1, n_chunks, chunk), xa_map),
             pl.BlockSpec((1, sp, n), bc_map),
             pl.BlockSpec((1, sp, n), bc_map),
         ],
@@ -139,7 +151,7 @@ def _ssd_scan_jit(x, a, B, C, *, chunk: int, interpret: bool):
             jax.ShapeDtypeStruct((bb * h, p, n), jnp.float32),
         ],
         interpret=interpret,
-    )(xt, at, B, C)
+    )(xt, a_col, a_row, B, C)
     y = y.reshape(bb, h, sp, p).transpose(0, 2, 1, 3)[:, :s]
     state = state.reshape(bb, h, p, n)
     return y, state

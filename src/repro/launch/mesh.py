@@ -8,6 +8,14 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with Auto axes: shardings propagate through jit
+    from the operands' placements (``make_mesh`` now defaults to Explicit
+    axes, under which sharding becomes part of every array's type)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,7 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (2, 16, 16) ('pod','data','model') = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -23,7 +31,7 @@ def make_host_mesh(model: int = 1):
     (tests / examples on CPU)."""
     n = jax.device_count()
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_request_mesh(data: int | None = None):
@@ -36,7 +44,7 @@ def make_request_mesh(data: int | None = None):
     BEFORE importing jax).
     """
     n = jax.device_count() if data is None else data
-    return jax.make_mesh((n,), ("data",))
+    return _auto_mesh((n,), ("data",))
 
 
 def mesh_fingerprint(mesh) -> tuple:

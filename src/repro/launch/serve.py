@@ -30,6 +30,8 @@ import argparse
 import asyncio
 import itertools
 import json
+import os
+import pathlib
 import threading
 
 import jax
@@ -207,10 +209,36 @@ async def _driver_demo(driver: ServeDriver, n_requests: int, seq_len: int):
     return await asyncio.gather(*[consume(h) for h in handles])
 
 
-def main():
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it stands (JAX reads
+    it itself) and nothing else is set. Otherwise the cache lives in a fixed
+    ``.jax_cache/`` at the root of the source checkout (a path that changed
+    between runs would never find its entries again). Run from anywhere
+    else, e.g. an installed package, it raises: set the variable there.
+    Entry points call this; importing the package never does."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    root = pathlib.Path(__file__).resolve().parents[3]
+    if not (root / "pyproject.toml").is_file():
+        raise RuntimeError(
+            f"no source checkout around {__file__} to keep a compile cache "
+            "in; set JAX_COMPILATION_CACHE_DIR")
+    path = str(root / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def make_parser() -> argparse.ArgumentParser:
+    """The serving CLI's options (shared with ``chip_smoke.py``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed of the randomly initialised params "
+                         "(before any --ckpt-dir restore)")
     ap.add_argument("--mode", choices=["ar", "diffusion"], default="diffusion")
     ap.add_argument("--transport", choices=["sync", "driver", "http"],
                     default="sync")
@@ -266,41 +294,61 @@ def main():
                          "('data',) mesh spanning every visible device "
                          "(force N host devices with "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    args = ap.parse_args()
+    return ap
 
+
+def load_model(args):
+    """(cfg, params) for ``--arch`` [``--reduced``], seeded by ``--seed``,
+    restored from ``--ckpt-dir`` when given."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     cfg = cfg.with_(objective="diffusion" if args.mode == "diffusion" else "ar")
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    # jitted, so each leaf is written once in its own dtype: eager init
+    # holds every layer twice (per-layer leaves, then their stack)
+    params = jax.jit(T.init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(args.seed))
     if args.ckpt_dir:
         params, _ = CKPT.restore(args.ckpt_dir, params)
         print(f"restored params from {args.ckpt_dir}")
+    return cfg, params
+
+
+def build_diffusion_engine(args, cfg, params) -> DiffusionServeEngine:
+    """The diffusion engine the serving options describe."""
+    mesh = None
+    if args.data_parallel:
+        from .mesh import make_request_mesh
+        mesh = make_request_mesh()
+        print(f"request-parallel mesh: {jax.device_count()} devices on "
+              "axis 'data' (group sizes round up to multiples)")
+    buckets = tuple(int(e) for e in args.seq_len_buckets.split(",")) \
+        if args.seq_len_buckets else None
+    retire = None
+    if args.early_exit_tol is not None:
+        from ..core.adaptive import RetirePolicy
+        retire = RetirePolicy(tol=args.early_exit_tol,
+                              min_k=args.early_exit_min_k,
+                              norm=args.early_exit_norm)
+        print(f"early exit on: {retire}")
+    return DiffusionServeEngine(params, cfg,
+                                steps_per_tick=args.steps_per_tick,
+                                compaction=not args.no_compaction,
+                                join=not args.no_join,
+                                seq_len_buckets=buckets,
+                                mesh=mesh,
+                                enforce_deadlines=args.enforce_deadlines,
+                                retire=retire)
+
+
+def main():
+    args = make_parser().parse_args()
+    enable_compile_cache()
+    cfg, params = load_model(args)
 
     if args.mode == "diffusion":
-        mesh = None
-        if args.data_parallel:
-            from .mesh import make_request_mesh
-            mesh = make_request_mesh()
-            print(f"request-parallel mesh: {jax.device_count()} devices on "
-                  "axis 'data' (group sizes round up to multiples)")
-        buckets = tuple(int(e) for e in args.seq_len_buckets.split(",")) \
-            if args.seq_len_buckets else None
-        retire = None
-        if args.early_exit_tol is not None:
-            from ..core.adaptive import RetirePolicy
-            retire = RetirePolicy(tol=args.early_exit_tol,
-                                  min_k=args.early_exit_min_k,
-                                  norm=args.early_exit_norm)
-            print(f"early exit on: {retire}")
-        eng = DiffusionServeEngine(params, cfg,
-                                   steps_per_tick=args.steps_per_tick,
-                                   compaction=not args.no_compaction,
-                                   join=not args.no_join,
-                                   seq_len_buckets=buckets,
-                                   mesh=mesh,
-                                   enforce_deadlines=args.enforce_deadlines,
-                                   retire=retire)
+        eng = build_diffusion_engine(args, cfg, params)
+        retire = eng.retire
         if args.trace_annotate:
             eng.tracer = Tracer(eng.metrics, annotate=True)
         exporter = NdjsonExporter(args.metrics_ndjson,

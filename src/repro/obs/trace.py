@@ -28,12 +28,9 @@ import threading
 import time
 from typing import Optional
 
-from .metrics import MetricsRegistry, DEFAULT_TIME_EDGES
+from jax.profiler import TraceAnnotation
 
-try:  # pragma: no cover - depends on jax build
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover
-    _TraceAnnotation = None
+from .metrics import MetricsRegistry, DEFAULT_TIME_EDGES
 
 
 class _NullSpan:
@@ -66,8 +63,8 @@ class _Span:
     def __enter__(self):
         tr = self._tracer
         tr._stack.path = self._path
-        if tr.annotate and _TraceAnnotation is not None:
-            self._ann = _TraceAnnotation(self._path)
+        if tr.annotate:
+            self._ann = TraceAnnotation(self._path)
             self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self

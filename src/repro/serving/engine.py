@@ -384,7 +384,7 @@ class DiffusionServeEngine:
                  retire: RetirePolicy | None = None,
                  metrics: MetricsRegistry | None = None,
                  tracer: Tracer | None = None,
-                 fused: bool | None = None):
+                 fused: bool = True):
         """``steps_per_tick``: groups advanced per tick (None = all active,
         the PR-2 behavior; an int enables true EDF selection).
         ``aging_ticks``: skipped ticks per +1 effective-priority boost
@@ -455,21 +455,20 @@ class DiffusionServeEngine:
         :class:`~repro.obs.trace.Tracer` for host-side span timing of
         ticks/steps/compiles/boundary work; ``None`` builds one over the
         same registry. Instrumentation is host-side only -- nothing here
-        syncs the device or touches the jitted step."""
-        """``fused``: route every ``ab``-method plan through the fused
+        syncs the device or touches the jitted step.
+
+        ``fused``: route every ``ab``-method plan through the fused
         Pallas megakernel step (psi/C combination + noise add + error-pair
         estimate in ONE kernel -- one HBM round-trip instead of r+3).
-        ``None`` (default) enables it whenever the kernel is importable.
-        Off only changes WHICH executor computes a step, never row content
-        across group compositions: stacked fused rows are bitwise identical
-        to solo fused rows (the row-block grid axis computes each row's
-        blocks independently)."""
+        On by default. Off only changes WHICH executor computes a step,
+        never row content across group compositions: stacked fused rows are
+        bitwise identical to solo fused rows (the row-block grid axis
+        computes each row's blocks independently)."""
         assert cfg.objective == "diffusion"
         self.params, self.cfg = params, cfg
         self.sde = sde or VPSDE()
         self.schedule = schedule
-        self.fused = (getattr(SAMPLER, "_fused_ab_step", None) is not None) \
-            if fused is None else bool(fused)
+        self.fused = bool(fused)
         self.max_group = max_group
         # clamp: 0/negative would make tick() select nothing and busy-loop
         self.steps_per_tick = None if steps_per_tick is None \
@@ -675,11 +674,14 @@ class DiffusionServeEngine:
             self._m_cache_hits.inc()
             return self._compiled[key_], 0.0
         self._m_cache_misses.inc()
-        cfg = self.cfg
+        cfg, mesh = self.cfg, self.mesh
 
         def run(params, plan_arg, k, st, lens):
             return SAMPLER.step(plan_arg, k, st,
-                                DLM.make_eps_fn(params, cfg, valid_len=lens))
+                                DLM.make_tiled_eps_fn(params, cfg,
+                                                      valid_len=lens,
+                                                      mesh=mesh),
+                                mesh=mesh)
 
         # k is lowered as a PER-ROW (R,) step vector: one trace serves both
         # groups admitted whole (all entries equal -- bitwise identical to a
@@ -688,8 +690,7 @@ class DiffusionServeEngine:
         # their padded tail keys out of attention, so sample content is
         # independent of the bucket the row landed in (full-length rows pass
         # lens == seq_len, an all-true mask).
-        k0 = jnp.zeros((state.x.shape[0],), jnp.int32)
-        lens0 = jnp.full((state.x.shape[0],), state.x.shape[1], jnp.int32)
+        rows = jax.ShapeDtypeStruct((state.x.shape[0],), jnp.int32)
         t0 = time.perf_counter()
         if self.mesh is None:
             jitted = jax.jit(run)
@@ -698,15 +699,14 @@ class DiffusionServeEngine:
             plan_sh, state_sh = self._shardings(plan, state)
             param_sh = jax.sharding.NamedSharding(
                 self.mesh, jax.sharding.PartitionSpec())
-            k_sh = to_shardings(step_index_specs(k0, self.mesh), self.mesh)
-            lens_sh = to_shardings(step_index_specs(lens0, self.mesh),
-                                   self.mesh)
-            jitted = jax.jit(run, in_shardings=(param_sh, plan_sh, k_sh,
-                                                state_sh, lens_sh),
+            row_sh = to_shardings(step_index_specs(rows, self.mesh),
+                                  self.mesh)
+            jitted = jax.jit(run, in_shardings=(param_sh, plan_sh, row_sh,
+                                                state_sh, row_sh),
                              out_shardings=state_sh)
         with self.tracer.span("compile"):
-            compiled = jitted.lower(self._params_exec, plan, k0,
-                                    state, lens0).compile()
+            compiled = jitted.lower(self._params_exec, plan, rows,
+                                    state, rows).compile()
         compile_s = time.perf_counter() - t0
         self._m_compile_s.inc(compile_s)
         self._compiled[key_] = compiled

@@ -22,11 +22,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
 
-# jax.shard_map is the modern alias; older jax ships it under experimental
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def moe_expert_parallel(params, cfg: ModelConfig, x, mesh, axis: str = "data"):
     """Expert-parallel MoE.
@@ -109,7 +104,7 @@ def moe_expert_parallel(params, cfg: ModelConfig, x, mesh, axis: str = "data"):
         lb = jax.lax.pmean(lb, axis)
         return out, lb
 
-    mapped = _shard_map(_ep, mesh=mesh, in_specs=in_specs,
-                        out_specs=(P(axis, None, None), P()))
+    mapped = jax.shard_map(_ep, mesh=mesh, in_specs=in_specs,
+                           out_specs=(P(axis, None, None), P()))
     out, lb = mapped(params, x)
     return out, {"moe_lb": lb}

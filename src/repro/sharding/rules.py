@@ -185,8 +185,9 @@ def to_shardings(spec_tree, mesh: Mesh):
 
 
 # -------------------------------------------- request-axis (serving) sharding
-def _leading_axis_spec(leaf, mesh: Mesh, dim: int) -> P:
-    """P with the data axes on ``dim`` when divisible, else replicated."""
+def request_axis_spec(leaf, mesh: Mesh, dim: int) -> P:
+    """P with the data axes on ``dim`` (a leaf's request axis) when
+    divisible, else replicated."""
     ba = batch_axes(mesh)
     parts: list = [None] * leaf.ndim
     if ba and dim < leaf.ndim and _div(leaf.shape[dim], mesh, ba):
@@ -206,7 +207,7 @@ def plan_specs(plan, mesh: Mesh):
     """
     stacked = getattr(plan, "stacked", False)
     return jax.tree.map(
-        lambda leaf: _leading_axis_spec(leaf, mesh, 0) if stacked else P(),
+        lambda leaf: request_axis_spec(leaf, mesh, 0) if stacked else P(),
         plan)
 
 
@@ -218,7 +219,7 @@ def step_index_specs(k, mesh: Mesh) -> P:
     request-axis leaves it indexes, so the per-row coefficient gather stays
     local to each shard; a group-uniform scalar ``k`` replicates.
     """
-    return _leading_axis_spec(k, mesh, 0) if getattr(k, "ndim", 0) else P()
+    return request_axis_spec(k, mesh, 0) if getattr(k, "ndim", 0) else P()
 
 
 def state_specs(state, mesh: Mesh):
@@ -234,8 +235,8 @@ def state_specs(state, mesh: Mesh):
     from ..core.sampler import SamplerState  # local: avoid core<->sharding cycle
     stacked = state.key.ndim == 2
     return SamplerState(
-        x=_leading_axis_spec(state.x, mesh, 0) if stacked else P(),
-        hist=_leading_axis_spec(state.hist, mesh, 1) if stacked else P(),
-        key=_leading_axis_spec(state.key, mesh, 0) if stacked else P(),
+        x=request_axis_spec(state.x, mesh, 0) if stacked else P(),
+        hist=request_axis_spec(state.hist, mesh, 1) if stacked else P(),
+        key=request_axis_spec(state.key, mesh, 0) if stacked else P(),
         k=P(),
-        err=_leading_axis_spec(state.err, mesh, 0) if stacked else P())
+        err=request_axis_spec(state.err, mesh, 0) if stacked else P())

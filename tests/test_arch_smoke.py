@@ -165,3 +165,35 @@ def test_pallas_kernel_routing_matches_xla(arch):
                   use_pallas=True)["logits"]
     np.testing.assert_allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32), rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("width", [256, 3840])
+def test_diffusion_eps_row_is_batch_invariant_bf16(width):
+    """A row's served eps in bf16 does not depend on how many rows share
+    the call -- the serving invariant (stacked row == solo row, bitwise) at
+    the served dtype. The batched forward breaks it at d_model 3840 even on
+    the CPU (its time MLP is a (B, D) product, which rounds differently at
+    B = 1 than at B = 4); the serving executors' eps, a loop over fixed
+    tiles of rows, holds at every group size."""
+    from repro.diffusion import lm as DLM
+    cfg = get_config("h2o_danube_3_4b").reduced().with_(
+        objective="diffusion", dtype="bfloat16", n_layers=1, d_model=width)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, width), jnp.float32)
+    t = jnp.linspace(0.3, 0.9, 4, dtype=jnp.float32)
+    lens = jnp.full((4,), 16, jnp.int32)
+    eps = jax.jit(lambda p, x, t, vl: DLM.make_tiled_eps_fn(
+        p, cfg, valid_len=vl)(x, t))
+    full = np.asarray(eps(params, x, t, lens))
+    for i in range(4):
+        np.testing.assert_array_equal(
+            full[i], np.asarray(eps(params, x[i:i + 1], t[i:i + 1],
+                                    lens[i:i + 1]))[0])
+    for r in (2, 3):   # a row's place in its tile, and a part-filled tile
+        np.testing.assert_array_equal(
+            full[:r], np.asarray(eps(params, x[:r], t[:r], lens[:r])))
+    # a one-tile group still runs the loop: XLA inlines a loop it can see
+    # runs once, which would give small groups a program of their own
+    for r in (1, 4):
+        assert "while(" in eps.lower(params, x[:r], t[:r],
+                                     lens[:r]).compile().as_text()

@@ -209,3 +209,45 @@ def test_http_transport_roundtrip(diff_setup):
             assert json.loads(lines[-1])["event"] == "error"
         finally:
             server.shutdown()
+
+
+def test_compile_cache_dir_prefers_env_then_checkout(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set; without
+    it the cache sits at a fixed ``.jax_cache/`` in the checkout."""
+    import pathlib
+    from repro.launch import serve
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert serve.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == prev
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = serve.enable_compile_cache()
+        assert path == str(pathlib.Path(__file__).resolve().parents[1]
+                           / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        # outside a source checkout (an installed package) it refuses
+        monkeypatch.setattr(serve, "__file__",
+                            str(tmp_path / "a" / "repro" / "launch" / "s.py"))
+        with pytest.raises(RuntimeError, match="JAX_COMPILATION_CACHE_DIR"):
+            serve.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_cli_builds_the_engine_its_options_describe():
+    """The serving CLI's constructors (shared with ``chip_smoke.py``):
+    ``--seed`` seeds the params, the engine is fused by default and takes
+    the early-exit policy from the options."""
+    from repro.launch import serve
+    argv = ["--arch", "h2o_danube_3_4b", "--reduced"]
+    parse = serve.make_parser().parse_args
+    args = parse(argv + ["--seed", "3", "--early-exit-tol", "1e-3"])
+    cfg, params = serve.load_model(args)
+    _, params0 = serve.load_model(parse(argv))
+    assert cfg.objective == "diffusion"
+    assert not np.array_equal(params["eps_head"], params0["eps_head"])
+    eng = serve.build_diffusion_engine(args, cfg, params)
+    assert eng.fused and eng.mesh is None
+    assert eng.retire is not None and eng.retire.tol == 1e-3

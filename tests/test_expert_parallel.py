@@ -31,9 +31,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (b, s, d))
 # reference: per-row single-device MoE (cap factor high => no drops)
 ref, aux_ref = L.moe(params, cfg, x)
 
-# jax.set_mesh is recent; older jax uses the Mesh context manager directly
-_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-with _ctx:
+with jax.set_mesh(mesh):
     out, aux = moe_expert_parallel(params, cfg, x, mesh, axis="data")
 err = float(jnp.abs(out - ref).max())
 print("max err:", err)
