@@ -43,7 +43,6 @@ no Python state), keeping the loop traceable.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 from typing import Callable, NamedTuple, Optional
@@ -545,20 +544,10 @@ def step(plan: SolverPlan, k, state: SamplerState, eps_fn: EpsFn, *,
 
 def sample(plan: SolverPlan, eps_fn: EpsFn, x_T: Array,
            key: Optional[Array] = None, *, hooks: Optional[Hooks] = None,
-           mesh=None, tracer=None):
+           mesh=None):
     """Run the full solve from ``x_T`` at ``ts[0]`` down to ``ts[-1]``.
 
     Returns ``x_0``, or ``(x_0, trajectory)`` if ``hooks.record_trajectory``.
-
-    ``tracer`` (a :class:`repro.obs.trace.Tracer`) opts into step-level
-    timing OFF the jitted path: the ab/rk loop runs as eagerly dispatched
-    steps instead of ``lax.fori_loop``, each wrapped in a ``sample.step``
-    span. Spans time host-side dispatch only and never force a device sync
-    (no ``block_until_ready`` anywhere); to attribute device time, construct
-    the tracer with ``annotate=True`` under a ``jax.profiler`` trace. Eager
-    stepping matches the fori_loop result to machine epsilon (same caveat as
-    ``sample`` vs an eagerly dispatched ``step`` loop above). Leave ``None``
-    on the hot path -- the traced loop stays byte-identical to before.
 
     ``mesh`` shards a *stacked* solve's request axis over the mesh's
     data-like axes before the loop; sharding propagates through the loop
@@ -577,15 +566,11 @@ def sample(plan: SolverPlan, eps_fn: EpsFn, x_T: Array,
     n = plan.n_steps
     stepper = _stepper(plan.method, mesh)
 
-    # pndm's warmup/tail differ structurally, so it always unrolls; a tracer
-    # forces the same eager loop for ab/rk so each step gets its own span.
-    if plan.method == "pndm" or tracer is not None:
-        span = (tracer.span if tracer is not None
-                else lambda _name: contextlib.nullcontext())
+    # pndm's warmup/tail differ structurally, so it always unrolls
+    if plan.method == "pndm":
         traj = []
         for k in range(n):
-            with span("sample.step"):
-                state = stepper(plan, k, state, eps_fn, hooks)
+            state = stepper(plan, k, state, eps_fn, hooks)
             if hooks.record_trajectory:
                 traj.append(state.x)
         return (state.x, jnp.stack(traj)) if hooks.record_trajectory else state.x

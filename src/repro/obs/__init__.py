@@ -5,8 +5,9 @@ The layer every perf/robustness PR reports through:
 
 * :mod:`repro.obs.metrics` -- thread-aware registry of counters / gauges /
   histograms with a lock-free fast path and a consistent ``snapshot()``;
-* :mod:`repro.obs.trace`   -- nestable span timers (engine ticks, group
-  steps, AOT compiles, join/compact boundaries) with optional
+* :mod:`repro.obs.trace`   -- nestable span timers (admission and its
+  joins, compactions and fresh groups, group steps, AOT compiles, decodes,
+  the driver's inbox and hand-back) with optional
   ``jax.profiler.TraceAnnotation`` pass-through so spans land in XLA
   profiles;
 * :mod:`repro.obs.export`  -- Prometheus-text and NDJSON renderers over a

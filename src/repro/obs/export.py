@@ -5,7 +5,10 @@ Two formats:
 * :func:`to_prometheus` -- the Prometheus text exposition format (0.0.4):
   ``# HELP``/``# TYPE`` headers, ``_bucket{le="..."}`` cumulative series +
   ``_sum``/``_count`` for histograms. This is what the serving launcher's
-  ``GET /metrics`` endpoint returns.
+  ``GET /metrics`` endpoint returns. Registry names may hold characters a
+  Prometheus name may not (a nested span's histogram is
+  ``trace_admit.form_seconds``); the renderer writes each such character
+  as ``_`` and leaves the registry's names as they are.
 * :func:`to_ndjson_line` / :class:`NdjsonExporter` -- one JSON object per
   snapshot (timestamped), appended as a line to a file. NDJSON is the
   offline twin of /metrics: point a ``--metrics-ndjson PATH`` run at a file
@@ -14,6 +17,7 @@ Two formats:
 from __future__ import annotations
 
 import json
+import re
 import time
 from typing import Optional
 
@@ -26,27 +30,39 @@ def _fmt(v: float) -> str:
     return str(int(f)) if f == int(f) else repr(f)
 
 
+_NOT_IN_NAME = re.compile(r"[^a-zA-Z0-9_:]")
+
+
+def _prom_name(name: str) -> str:
+    """``name`` as a valid Prometheus metric name (``[a-zA-Z_:][a-zA-Z0-9_:]*``):
+    every other character becomes ``_``, and a leading digit gets a ``_``
+    in front."""
+    out = _NOT_IN_NAME.sub("_", name)
+    return "_" + out if not out or out[0].isdigit() else out
+
+
 def to_prometheus(registry: MetricsRegistry) -> str:
     """Render the registry in the Prometheus text exposition format."""
     lines: list[str] = []
     for m in registry:
+        name = _prom_name(m.name)
         if isinstance(m, Counter):
-            lines.append(f"# HELP {m.name} {m.help}")
-            lines.append(f"# TYPE {m.name} counter")
-            lines.append(f"{m.name} {_fmt(m.value)}")
+            lines.append(f"# HELP {name} {m.help}")
+            lines.append(f"# TYPE {name} counter")
+            lines.append(f"{name} {_fmt(m.value)}")
         elif isinstance(m, Gauge):
-            lines.append(f"# HELP {m.name} {m.help}")
-            lines.append(f"# TYPE {m.name} gauge")
-            lines.append(f"{m.name} {_fmt(m.value)}")
+            lines.append(f"# HELP {name} {m.help}")
+            lines.append(f"# TYPE {name} gauge")
+            lines.append(f"{name} {_fmt(m.value)}")
         elif isinstance(m, Histogram):
-            lines.append(f"# HELP {m.name} {m.help}")
-            lines.append(f"# TYPE {m.name} histogram")
+            lines.append(f"# HELP {name} {m.help}")
+            lines.append(f"# TYPE {name} histogram")
             cum = m.cumulative()
             for edge, c in zip(m.edges, cum):
-                lines.append(f'{m.name}_bucket{{le="{_fmt(edge)}"}} {c}')
-            lines.append(f'{m.name}_bucket{{le="+Inf"}} {cum[-1]}')
-            lines.append(f"{m.name}_sum {_fmt(m.sum)}")
-            lines.append(f"{m.name}_count {m.count}")
+                lines.append(f'{name}_bucket{{le="{_fmt(edge)}"}} {c}')
+            lines.append(f'{name}_bucket{{le="+Inf"}} {cum[-1]}')
+            lines.append(f"{name}_sum {_fmt(m.sum)}")
+            lines.append(f"{name}_count {m.count}")
     return "\n".join(lines) + "\n"
 
 

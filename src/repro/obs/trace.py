@@ -1,11 +1,12 @@
 """Nestable span timers for the serving stack's host-side phases.
 
-A :class:`Tracer` times named spans -- engine ticks, group-step dispatch,
-AOT compiles, join/compact boundary work -- and feeds each duration into a
-per-span-name histogram of a :class:`~repro.obs.metrics.MetricsRegistry`.
-Spans nest (``tick`` > ``admit`` > ``join``); the tracer keeps a thread-local
-stack so the recorded name is the dotted path of its ancestry, which is what
-``docs/observability.md`` documents as the span hierarchy.
+A :class:`Tracer` times named spans -- admission and its parts, group-step
+dispatch and wait, AOT compiles, decodes, the driver's inbox and hand-back
+-- and feeds each duration into a per-span-path histogram of a
+:class:`~repro.obs.metrics.MetricsRegistry`. Spans nest (``admit`` >
+``form`` > ``prior``); the tracer keeps a thread-local stack so the
+recorded name is the dotted path of its ancestry (``admit.form.prior``),
+which is what ``docs/observability.md`` documents as the span hierarchy.
 
 Two hard rules, both about the jitted hot path:
 
@@ -14,9 +15,11 @@ Two hard rules, both about the jitted hot path:
   device sync itself -- there is no ``block_until_ready`` anywhere in this
   module.
 * with ``annotate=True`` each span also enters a
-  ``jax.profiler.TraceAnnotation``, so the same span names show up attached
-  to device work in XLA/perfetto profiles. The annotation is a no-op unless
-  a profiler trace is being collected; it adds no sync either.
+  ``jax.profiler.TraceAnnotation`` named by its dotted path, carrying the
+  span's keyword arguments as event stats, so the same names show up on the
+  device trace's clock in XLA/perfetto profiles. The annotation is a no-op
+  unless a profiler trace is being collected; it adds no sync either.
+  Without ``annotate`` the keyword arguments are dropped unread.
 
 ``NULL_TRACER`` is the disabled instance: its ``span()`` is a reusable
 no-op context manager, so instrumented code never branches on "is tracing
@@ -30,7 +33,7 @@ from typing import Optional
 
 from jax.profiler import TraceAnnotation
 
-from .metrics import MetricsRegistry, DEFAULT_TIME_EDGES
+from .metrics import DEFAULT_TIME_EDGES, Histogram, MetricsRegistry
 
 
 class _NullSpan:
@@ -52,19 +55,21 @@ class _Span:
     the tracer's histogram for the span's dotted path. The parent path is
     carried explicitly (not recomputed from the dotted string) so span
     NAMES may themselves contain dots."""
-    __slots__ = ("_tracer", "_path", "_parent", "_t0", "_ann")
+    __slots__ = ("_tracer", "_path", "_parent", "_args", "_t0", "_ann")
 
-    def __init__(self, tracer: "Tracer", path: str, parent: str):
+    def __init__(self, tracer: "Tracer", path: str, parent: str,
+                 args: Optional[dict]):
         self._tracer = tracer
         self._path = path
         self._parent = parent
+        self._args = args
         self._ann = None
 
     def __enter__(self):
         tr = self._tracer
         tr._stack.path = self._path
-        if tr.annotate:
-            self._ann = TraceAnnotation(self._path)
+        if self._args is not None:
+            self._ann = TraceAnnotation(self._path, **self._args)
             self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -82,11 +87,12 @@ class _Span:
 class Tracer:
     """Span-timer bound to a metrics registry.
 
-    ``tracer.span("tick")`` inside ``tracer.span("serve")`` records into the
-    histogram ``<prefix>span_seconds`` under the dotted path ``serve.tick``
-    -- one histogram per distinct path, registered lazily. The nesting
-    stack is thread-local, so transport threads and the scheduler thread
-    can trace concurrently without mixing ancestries.
+    ``tracer.span("form")`` inside ``tracer.span("admit")`` records into the
+    histogram ``<prefix>admit.form_seconds`` -- one histogram per distinct
+    path, registered on its first use and kept by the tracer, so a span's
+    exit never takes the registry's lock. The nesting stack is
+    thread-local, so transport threads and the scheduler thread can trace
+    concurrently without mixing ancestries.
     """
 
     def __init__(self, registry: Optional[MetricsRegistry] = None, *,
@@ -96,6 +102,7 @@ class Tracer:
         self.prefix = prefix
         self.annotate = annotate
         self._edges = edges
+        self._hists: dict[str, Histogram] = {}
         self._stack = threading.local()
         self._stack.path = ""
 
@@ -103,14 +110,21 @@ class Tracer:
     def _current(self) -> str:
         return getattr(self._stack, "path", "")
 
-    def span(self, name: str) -> _Span:
+    def span(self, name: str, **args) -> _Span:
+        """A span named ``name`` under the thread's open span. ``args``
+        (numbers or strings) ride on the profiler annotation when
+        ``annotate`` is on, and are dropped otherwise."""
         parent = self._current()
-        return _Span(self, f"{parent}.{name}" if parent else name, parent)
+        return _Span(self, f"{parent}.{name}" if parent else name, parent,
+                     args if self.annotate else None)
 
     def _observe(self, path: str, dt: float) -> None:
-        self.registry.histogram(
-            f"{self.prefix}{path}_seconds",
-            help=f"span duration: {path}", edges=self._edges).observe(dt)
+        h = self._hists.get(path)
+        if h is None:
+            h = self._hists[path] = self.registry.histogram(
+                f"{self.prefix}{path}_seconds",
+                help=f"span duration: {path}", edges=self._edges)
+        h.observe(dt)
 
     def span_names(self) -> list[str]:
         """Dotted span paths recorded so far (for tests/docs)."""
@@ -125,7 +139,7 @@ class _NullTracer(Tracer):
     def __init__(self):
         super().__init__(MetricsRegistry())
 
-    def span(self, name: str):  # type: ignore[override]
+    def span(self, name: str, **args):  # type: ignore[override]
         return _NULL_SPAN
 
 

@@ -341,30 +341,38 @@ class ServeDriver:
 
     # ------------------------------------------------------------ scheduler
     def _drain_inbox(self, block: bool) -> None:
+        """Hand what the inbox holds to the engine. ``block``: the engine
+        is idle, so wait up to ``idle_wait_s`` for the first item (the
+        ``idle`` span); the hand-over is the ``inbox`` span."""
+        tracer = self.engine.tracer
         try:
-            first = self._inbox.get(timeout=self.idle_wait_s) if block \
-                else self._inbox.get_nowait()
+            if block:
+                with tracer.span("idle"):
+                    first = self._inbox.get(timeout=self.idle_wait_s)
+            else:
+                first = self._inbox.get_nowait()
         except queue.Empty:
             return
-        batch = [first]
-        while True:
-            try:
-                batch.append(self._inbox.get_nowait())
-            except queue.Empty:
-                break
-        for req, stream in batch:
-            if req is _CANCEL:
-                # stream here is the uid; engine emits the cancelled Result
-                # at the next tick (False = already finished: no-op, the
-                # delivered Result stands)
-                self.engine.cancel(stream)
-                continue
-            try:
-                self.engine.submit(req)
-            except Exception as e:  # per-request failure, not batch-fatal
-                with self._lock:
-                    self._streams.pop(req.uid, None)
-                stream._fail(e)
+        with tracer.span("inbox"):
+            batch = [first]
+            while True:
+                try:
+                    batch.append(self._inbox.get_nowait())
+                except queue.Empty:
+                    break
+            for req, stream in batch:
+                if req is _CANCEL:
+                    # stream here is the uid; engine emits the cancelled
+                    # Result at the next tick (False = already finished:
+                    # no-op, the delivered Result stands)
+                    self.engine.cancel(stream)
+                    continue
+                try:
+                    self.engine.submit(req)
+                except Exception as e:  # per-request failure, not batch-fatal
+                    with self._lock:
+                        self._streams.pop(req.uid, None)
+                    stream._fail(e)
 
     def _fanout(self, event: StepEvent) -> None:
         """Engine ``on_step`` callback: slice the group event per request.
@@ -410,6 +418,34 @@ class ServeDriver:
         for stream in streams.values():
             stream._fail(exc)
 
+    def _resolve(self, results: list[Result]) -> None:
+        """Hand each finished Result to its stream: the sample, or the
+        request's own cancellation or deadline error."""
+        for res in results:
+            with self._lock:
+                stream = self._streams.pop(res.uid, None)
+            if stream is None:
+                continue
+            if res.cancelled:
+                exc = Cancelled(
+                    f"request uid {res.uid} cancelled after "
+                    f"{res.latency_s:.3f}s of solve time")
+                exc.result = res
+                stream._fail(exc)
+            elif res.deadline_exceeded:
+                # Deadline eviction is a per-request outcome, never a
+                # driver crash: the engine recycled the row and this
+                # request's own future carries the error (with the
+                # partial Result attached for latency accounting).
+                exc = DeadlineExceeded(
+                    f"request uid {res.uid} evicted: absolute "
+                    f"deadline passed after {res.latency_s:.3f}s of "
+                    "solve time")
+                exc.result = res
+                stream._fail(exc)
+            else:
+                stream._finish(res)
+
     def _run(self) -> None:
         while True:
             busy = self.engine.busy
@@ -423,30 +459,9 @@ class ServeDriver:
                 except Exception as e:   # noqa: BLE001 - fail open, keep serving
                     self._crash(e)
                     continue
-                for res in results:
-                    with self._lock:
-                        stream = self._streams.pop(res.uid, None)
-                    if stream is None:
-                        continue
-                    if res.cancelled:
-                        exc = Cancelled(
-                            f"request uid {res.uid} cancelled after "
-                            f"{res.latency_s:.3f}s of solve time")
-                        exc.result = res
-                        stream._fail(exc)
-                    elif res.deadline_exceeded:
-                        # Deadline eviction is a per-request outcome, never a
-                        # driver crash: the engine recycled the row and this
-                        # request's own future carries the error (with the
-                        # partial Result attached for latency accounting).
-                        exc = DeadlineExceeded(
-                            f"request uid {res.uid} evicted: absolute "
-                            f"deadline passed after {res.latency_s:.3f}s of "
-                            "solve time")
-                        exc.result = res
-                        stream._fail(exc)
-                    else:
-                        stream._finish(res)
+                if results:
+                    with self.engine.tracer.span("resolve"):
+                        self._resolve(results)
                 self._h_loop.observe(time.perf_counter() - t0)
             elif self._stop.is_set() and self._inbox.empty():
                 return

@@ -925,9 +925,11 @@ class DiffusionServeEngine:
         join/compaction path every retired row goes through."""
         now = time.perf_counter()
         if self.enforce_deadlines:
-            self._evict_expired(now)
+            with self.tracer.span("evict"):
+                self._evict_expired(now)
         if self.retire is not None:
-            self._retire_converged()
+            with self.tracer.span("retire"):
+                self._retire_converged()
         buckets: dict = {}
         while self._pending:
             p = self._pending.popleft()
@@ -940,13 +942,17 @@ class DiffusionServeEngine:
             for g in sorted(self._active, key=self._group_key):
                 if not any(r.done for r in g.rows):
                     continue
-                cands = buckets.get(g.bucket) if self.join else None
-                if cands and self._join_group(g, cands, now):
+                take = (self._joiners(g, buckets.get(g.bucket))
+                        if self.join else [])
+                if take:
+                    with self.tracer.span("join"):
+                        self._join_group(g, take, now)
                     continue
                 live = [i for i, r in enumerate(g.rows) if not r.done]
                 keep = self._compact_target(g, live)
                 if keep is not None:
-                    self._compact(g, keep)
+                    with self.tracer.span("compact"):
+                        self._compact(g, keep)
                 else:
                     # the group already sits at the smallest placeable
                     # multiple of the data axis (mesh only: unsharded groups
@@ -956,76 +962,88 @@ class DiffusionServeEngine:
                     for r in g.rows:
                         if r.done:
                             r.pad = True
-        for (_fam, s_len), items in buckets.items():
+        for (fam, s_len), items in buckets.items():
             for i in range(0, len(items), self._chunk_cap):
-                chunk = items[i:i + self._chunk_cap]
-                n_max = max(p.plan.n_steps for p in chunk)
-                padded = [pad_plan(p.plan, n_max) for p in chunk]
-                rows = [_Row(req=p.req, n_steps=p.plan.n_steps,
-                             nfe=p.plan.nfe,
-                             deadline=self._abs_deadline(p.req, p.t_sub),
-                             wait_s=now - p.t_sub)
-                        for p in chunk]
-                seeds = [p.req.seed for p in chunk]
-                n_fill = (-len(chunk)) % self._data_size
-                if n_fill:
-                    filler = inert_row(padded[0])
-                    padded += [filler] * n_fill
-                    rows += [_Row(req=None, n_steps=n_max, nfe=0,
-                                  deadline=math.inf, done=True, pad=True)
-                             for _ in range(n_fill)]
-                    seeds += [0] * n_fill
-                sig = padded[0].signature
-                plan = stack_plans(padded)
-                keys = DLM.request_keys(seeds)
-                state = DLM.init_sample_state(
-                    self.cfg, plan, keys, seq_len=s_len,
-                    prior_std=self.sde.prior_std(),
-                    valid_lens=[p.req.seq_len for p in chunk]
-                    + [s_len] * n_fill)
-                fn, compile_s = self._executor(sig, plan, state)
-                plan_sh, state_sh = self._shardings(plan, state)
-                if plan_sh is not None:
-                    plan = jax.device_put(plan, plan_sh)
-                    state = jax.device_put(state, state_sh)
-                reqs = [p.req for p in chunk]
-                self._arrivals += 1
-                self._active.append(_Group(
-                    rows=rows, sig=sig, bucket=(_fam, s_len), seq_len=s_len,
-                    plan=plan, state=state, fn=fn,
-                    n_steps=n_max, compile_s=compile_s,
-                    priority=max(r.priority for r in reqs),
-                    deadline=min(r.deadline for r in rows),
-                    arrival=self._arrivals))
+                with self.tracer.span("form"):
+                    self._form_group(items[i:i + self._chunk_cap], fam,
+                                     s_len, now)
 
-    def _join_group(self, g: _Group, cands: list, now: float) -> bool:
-        """Splice pending requests into ``g`` at a compaction boundary.
+    def _form_group(self, chunk: list, fam: str, s_len: int,
+                    now: float) -> None:
+        """One fresh group from ``chunk`` (same family and bucketed
+        seq_len): plans padded to the longest grid and stacked, filler rows
+        up to a data-axis multiple, priors drawn, executor looked up (or
+        compiled) and the group placed."""
+        n_max = max(p.plan.n_steps for p in chunk)
+        padded = [pad_plan(p.plan, n_max) for p in chunk]
+        rows = [_Row(req=p.req, n_steps=p.plan.n_steps,
+                     nfe=p.plan.nfe,
+                     deadline=self._abs_deadline(p.req, p.t_sub),
+                     wait_s=now - p.t_sub)
+                for p in chunk]
+        seeds = [p.req.seed for p in chunk]
+        n_fill = (-len(chunk)) % self._data_size
+        if n_fill:
+            filler = inert_row(padded[0])
+            padded += [filler] * n_fill
+            rows += [_Row(req=None, n_steps=n_max, nfe=0,
+                          deadline=math.inf, done=True, pad=True)
+                     for _ in range(n_fill)]
+            seeds += [0] * n_fill
+        sig = padded[0].signature
+        plan = stack_plans(padded)
+        with self.tracer.span("prior"):
+            keys = DLM.request_keys(seeds)
+            state = DLM.init_sample_state(
+                self.cfg, plan, keys, seq_len=s_len,
+                prior_std=self.sde.prior_std(),
+                valid_lens=[p.req.seq_len for p in chunk]
+                + [s_len] * n_fill)
+        fn, compile_s = self._executor(sig, plan, state)
+        plan_sh, state_sh = self._shardings(plan, state)
+        if plan_sh is not None:
+            plan = jax.device_put(plan, plan_sh)
+            state = jax.device_put(state, state_sh)
+        reqs = [p.req for p in chunk]
+        self._arrivals += 1
+        self._active.append(_Group(
+            rows=rows, sig=sig, bucket=(fam, s_len), seq_len=s_len,
+            plan=plan, state=state, fn=fn,
+            n_steps=n_max, compile_s=compile_s,
+            priority=max(r.priority for r in reqs),
+            deadline=min(r.deadline for r in rows),
+            arrival=self._arrivals))
 
-        ``cands`` is the group's admission bucket, urgency-sorted; joiners
-        are taken from the front, skipping any whose grid exceeds the
-        group's horizon (they form fresh groups instead -- extending the
-        grid would change the signature and recompile). The rebuilt batch
-        keeps the surviving rows in their original relative order, each
-        carried whole and bitwise-unmoved (``take_rows`` of the survivors,
-        then ``join_rows`` appending the padded joiners), rounds up to a
-        data-axis multiple reusing retired rows as slots before allocating
-        inert filler, and stays within ``max_group``. Joiner
-        rows record ``k0 = g.k`` (their steps count from THIS tick) and
-        ``solve_s0`` (their latency excludes the group's past). Returns
-        False when nothing could join (caller falls back to compaction)."""
-        live = [i for i, r in enumerate(g.rows) if not r.done]
-        cap = self._chunk_cap - len(live)
-        if cap <= 0:
-            return False
+    def _joiners(self, g: _Group, cands: list | None) -> list:
+        """The pending requests that join ``g`` at this boundary, removed
+        from ``cands`` (the group's admission bucket, urgency-sorted):
+        taken from the front up to the group's free slots, skipping any
+        whose grid exceeds the group's horizon (they form fresh groups
+        instead -- extending the grid would change the signature and
+        recompile). Empty when nothing can join (the caller compacts)."""
+        cap = self._chunk_cap - sum(not r.done for r in g.rows)
+        if not cands or cap <= 0:
+            return []
         take, rest = [], []
         for p in cands:
             if len(take) < cap and p.plan.n_steps <= g.plan.n_steps:
                 take.append(p)
             else:
                 rest.append(p)
-        if not take:
-            return False
         cands[:] = rest
+        return take
+
+    def _join_group(self, g: _Group, take: list, now: float) -> None:
+        """Splice the pending requests ``take`` (see :meth:`_joiners`) into
+        ``g`` at a compaction boundary. The rebuilt batch
+        keeps the surviving rows in their original relative order, each
+        carried whole and bitwise-unmoved (``take_rows`` of the survivors,
+        then ``join_rows`` appending the padded joiners), rounds up to a
+        data-axis multiple reusing retired rows as slots before allocating
+        inert filler, and stays within ``max_group``. Joiner
+        rows record ``k0 = g.k`` (their steps count from THIS tick) and
+        ``solve_s0`` (their latency excludes the group's past)."""
+        live = [i for i, r in enumerate(g.rows) if not r.done]
         keep, n_inert = self._round_keep(g, live, len(take))
         plan_sh, state_sh = self._shardings(g.plan, g.state)
         if keep != list(range(len(g.rows))):
@@ -1052,12 +1070,13 @@ class DiffusionServeEngine:
             new_rows += [_Row(req=None, n_steps=0, nfe=0, deadline=math.inf,
                               done=True, pad=True, k0=g.k)
                          for _ in range(n_inert)]
-        keys = DLM.request_keys(seeds)
-        add_state = DLM.init_sample_state(
-            self.cfg, stack_plans(padded), keys, seq_len=g.seq_len,
-            prior_std=self.sde.prior_std(),
-            valid_lens=[p.req.seq_len for p in take]
-            + [g.seq_len] * n_inert)
+        with self.tracer.span("prior"):
+            keys = DLM.request_keys(seeds)
+            add_state = DLM.init_sample_state(
+                self.cfg, stack_plans(padded), keys, seq_len=g.seq_len,
+                prior_std=self.sde.prior_std(),
+                valid_lens=[p.req.seq_len for p in take]
+                + [g.seq_len] * n_inert)
         g.plan = join_rows(g.plan, padded, shardings=plan_sh)
         g.state = SAMPLER.join_state_rows(g.state, add_state,
                                           shardings=state_sh)
@@ -1069,7 +1088,6 @@ class DiffusionServeEngine:
         g.fn, compile_s = self._executor(g.sig, g.plan, g.state)
         g.compile_s += compile_s
         self._m_joined.inc(len(take))
-        return True
 
     def _select(self) -> tuple[list[_Group], list[_Group]]:
         """Order active groups by urgency; return (stepped, skipped).
@@ -1191,7 +1209,14 @@ class DiffusionServeEngine:
         group's solve time since the row's admission and the row's true
         ``nfe``. Groups with only finished rows are retired; groups left
         with retired rows rebuild (join or compact) at the next tick's
-        admission boundary, before they step again."""
+        admission boundary, before they step again.
+
+        Spans on ``self.tracer``: ``admit`` (inside it ``evict``,
+        ``retire``, ``join``, ``compact``, ``form``, and ``prior`` and
+        ``compile`` beneath those), ``dispatch``, ``step_wait`` per group,
+        ``decode`` and ``fanout``. No span covers the tick as a whole: a
+        profile names each idle stretch of the device by the innermost
+        span open over it."""
         t_tick = time.perf_counter()
         with self.tracer.span("admit"):
             self._admit()
@@ -1224,7 +1249,10 @@ class DiffusionServeEngine:
                                lens_vec)
                 dispatched.append((g, t0))
         for g, t0 in dispatched:
-            with self.tracer.span("step_wait"):
+            # rows: live request rows; slots: rows the executor steps
+            with self.tracer.span("step_wait",
+                                  rows=sum(not r.done for r in g.rows),
+                                  slots=len(g.rows), seq=g.seq_len):
                 # repro: allow[RL001] THE documented boundary sync: one wait per
                 # group-step after all groups dispatched (see module docstring)
                 jax.block_until_ready(g.state.x)
@@ -1237,22 +1265,27 @@ class DiffusionServeEngine:
             # decode against the as-placed params (replicated under a mesh):
             # a data-sharded iterate composes with them eagerly, so the
             # sharded and unsharded paths share one decode expression
-            stream_toks = None
-            if on_step is not None and stream_decode:
-                # repro: allow[RL001] opt-in stream decode: caller chose per-step
-                # token delivery over peak throughput
-                stream_toks = np.asarray(DLM.decode_tokens(
-                    self._params_exec, self.cfg, g.state.x))
+            stream_toks = err_v = None
+            want_toks = on_step is not None and stream_decode
             # one host pull of the per-row error estimates serves both the
             # step event and natural-finish final_err (plans without
             # embedded pairs skip the transfer entirely)
-            err_v = None
-            if g.plan.error_estimate and (on_step is not None or newly):
-                # repro: allow[RL001] single err pull serves step event + final_err
-                err_v = np.asarray(jax.device_get(g.state.err), np.float64)
+            want_err = g.plan.error_estimate and (on_step is not None
+                                                  or newly)
+            if want_toks or want_err:
+                with self.tracer.span("decode"):
+                    if want_toks:
+                        # repro: allow[RL001] opt-in stream decode: the caller
+                        # chose per-step token delivery over peak throughput
+                        stream_toks = np.asarray(DLM.decode_tokens(
+                            self._params_exec, self.cfg, g.state.x))
+                    if want_err:
+                        # repro: allow[RL001] single err pull serves step event + final_err
+                        err_v = np.asarray(jax.device_get(g.state.err),
+                                           np.float64)
             if on_step is not None:
                 real = g.real_idx
-                on_step(StepEvent(
+                event = StepEvent(
                     uids=g.uids, k=g.k, n_steps=g.n_steps,
                     tokens=stream_toks[real] if stream_toks is not None
                     else None,
@@ -1260,35 +1293,39 @@ class DiffusionServeEngine:
                     row_k=tuple(g.k - g.rows[i].k0 for i in real),
                     row_seq_lens=tuple(g.rows[i].req.seq_len for i in real),
                     row_err=tuple(float(err_v[i]) for i in real)
-                    if err_v is not None else None))
+                    if err_v is not None else None)
+                with self.tracer.span("fanout"):
+                    on_step(event)
             if newly:
-                # decode ONLY the finished rows unless a full partial decode
-                # already exists (ragged groups would otherwise pay one
-                # full-batch decode per distinct member NFE)
-                new_toks = (stream_toks[newly] if stream_toks is not None
-                            # repro: allow[RL001] finished rows leave the device here by design
-                            else np.asarray(DLM.decode_tokens(
-                                self._params_exec, self.cfg,
-                                g.state.x[jnp.asarray(newly)])))
-                for j, i in enumerate(newly):
-                    row = g.rows[i]
-                    row.done = True
-                    # bucketed admission: mask the solve's tail positions
-                    # back to the request's true seq_len. final_err is None
-                    # (not +inf) when no estimate exists: Results serialize
-                    # to strict JSON, which has no Infinity literal.
-                    f_err = None
-                    if err_v is not None and math.isfinite(err_v[i]):
-                        f_err = float(err_v[i])
-                    res = Result(
-                        row.req.uid, new_toks[j][:row.req.seq_len],
-                        g.solve_s - row.solve_s0, nfe=row.nfe,
-                        compile_s=g.compile_s, queue_wait_s=row.wait_s,
-                        final_err=f_err)
-                    self._m_completed.inc()
-                    self._h_queue_wait.observe(res.queue_wait_s)
-                    self._h_solve.observe(res.latency_s)
-                    finished.append(res)
+                with self.tracer.span("decode"):
+                    # decode ONLY the finished rows unless a full partial
+                    # decode already exists (ragged groups would otherwise
+                    # pay one full-batch decode per distinct member NFE)
+                    new_toks = (stream_toks[newly] if stream_toks is not None
+                                # repro: allow[RL001] finished rows leave the device here by design
+                                else np.asarray(DLM.decode_tokens(
+                                    self._params_exec, self.cfg,
+                                    g.state.x[jnp.asarray(newly)])))
+                    for j, i in enumerate(newly):
+                        row = g.rows[i]
+                        row.done = True
+                        # bucketed admission: mask the solve's tail
+                        # positions back to the request's true seq_len.
+                        # final_err is None (not +inf) when no estimate
+                        # exists: Results serialize to strict JSON, which
+                        # has no Infinity literal.
+                        f_err = None
+                        if err_v is not None and math.isfinite(err_v[i]):
+                            f_err = float(err_v[i])
+                        res = Result(
+                            row.req.uid, new_toks[j][:row.req.seq_len],
+                            g.solve_s - row.solve_s0, nfe=row.nfe,
+                            compile_s=g.compile_s, queue_wait_s=row.wait_s,
+                            final_err=f_err)
+                        self._m_completed.inc()
+                        self._h_queue_wait.observe(res.queue_wait_s)
+                        self._h_solve.observe(res.latency_s)
+                        finished.append(res)
             if not any(not r.done for r in g.rows):
                 self._active.remove(g)
         self._g_groups.set(len(self._active))

@@ -129,6 +129,32 @@ def test_prometheus_text_format():
     assert text.endswith("\n")
 
 
+def test_prometheus_names_are_valid_for_nested_spans():
+    """A nested span's histogram (``trace_admit.form_seconds``) and any other
+    registry name render as valid Prometheus names; the registry keeps its
+    own names."""
+    import re
+    reg = MetricsRegistry()
+    tr = Tracer(reg)
+    with tr.span("admit"):
+        with tr.span("form"):
+            pass
+    reg.counter("9lives-total").inc()
+    reg.gauge("queue depth").set(2)
+    text = to_prometheus(reg)
+    valid = re.compile(r"[a-zA-Z_:][a-zA-Z0-9_:]*")
+    names = []
+    for line in text.splitlines():
+        if line.startswith("#"):
+            names.append(line.split()[2])
+        else:
+            names.append(re.split(r"[{ ]", line, maxsplit=1)[0])
+    assert names and all(valid.fullmatch(n) for n in names), names
+    assert "trace_admit_form_seconds_count 1" in text
+    assert "_9lives_total 1" in text
+    assert "trace_admit.form_seconds" in reg
+
+
 def test_ndjson_line_and_exporter(tmp_path):
     reg = MetricsRegistry()
     reg.counter("c_total", help="c").inc()
@@ -177,6 +203,51 @@ def test_null_tracer_records_nothing():
     with NULL_TRACER.span("anything"):
         pass
     assert NULL_TRACER.span_names() == []
+
+
+def test_tracer_registers_each_histogram_once():
+    reg = MetricsRegistry()
+    calls = []
+    register = reg.histogram
+
+    def counted(name, *a, **kw):
+        calls.append(name)
+        return register(name, *a, **kw)
+    reg.histogram = counted
+    tr = Tracer(reg)
+    for _ in range(3):
+        with tr.span("admit"):
+            with tr.span("form"):
+                pass
+    assert sorted(calls) == ["trace_admit.form_seconds", "trace_admit_seconds"]
+    assert reg.get("trace_admit.form_seconds").count == 3
+
+
+@pytest.mark.parametrize("annotate", [False, True])
+def test_span_args_reach_the_annotation_only_when_annotating(annotate,
+                                                             monkeypatch):
+    from repro.obs import trace as obs_trace
+    made = []
+
+    class Annotation:
+        def __init__(self, name, **args):
+            made.append((name, args))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", Annotation)
+    tr = Tracer(MetricsRegistry(), annotate=annotate)
+    with tr.span("step_wait", rows=3, slots=4, seq=256):
+        with tr.span("inner"):
+            pass
+    want = [("step_wait", {"rows": 3, "slots": 4, "seq": 256}),
+            ("step_wait.inner", {})]
+    assert made == (want if annotate else [])
+    assert tr.span_names() == ["step_wait", "step_wait.inner"]
 
 
 # -------------------------------------------------------------------- bench

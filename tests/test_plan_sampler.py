@@ -355,29 +355,6 @@ def test_plan_nfe_accounting():
     assert make_plan("rho_rk4", SDE, TS).nfe == 32
 
 
-# ------------------------------------------------------- step-level tracing
-@pytest.mark.parametrize("name", ["tab3", "pndm"])
-def test_sample_with_tracer_matches_untraced(name):
-    """``sample(..., tracer=...)`` swaps the fori_loop for eagerly
-    dispatched steps and records one ``sample.step`` span per step -- and
-    the result matches the untraced solve (bitwise for pndm, which eagerly
-    unrolls either way; to solver tolerance for ab/rk, where XLA may fuse
-    the loop body differently)."""
-    from repro.obs import MetricsRegistry, Tracer
-
-    eps, xT = _problem()
-    plan = make_plan(name, SDE, TS)
-    want = sample(plan, eps, xT)
-    tr = Tracer(MetricsRegistry())
-    got = sample(plan, eps, xT, tracer=tr)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
-    if name.startswith("pndm"):
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert tr.span_names() == ["sample.step"]
-    assert tr.registry.get("trace_sample.step_seconds").count == plan.n_steps
-
-
 # ------------------------------------- transfer guard: dynamic twin of RL001
 # One solver per stepper family (ab/rk/stochastic/pndm): the full solve must
 # run without a single implicit device<->host transfer -- the runtime check
