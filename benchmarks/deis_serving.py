@@ -205,8 +205,8 @@ def _continuous_requests(quick: bool):
 
 def _run_continuous(params, cfg, arrivals, *, continuous: bool):
     """Cold pass (compiles), then a warm measured pass of the staggered
-    stream under a throttled scheduler. ``continuous`` enables
-    join-at-compaction (+ compaction); off is the static-admission world
+    stream under a throttled scheduler. ``continuous`` enables joins
+    into in-flight groups (+ compaction); off is the static-admission world
     where every wave forms its own group and dead rows ride along.
 
     Queue wait is per-request end-to-end time MINUS its solve latency

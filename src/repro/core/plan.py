@@ -272,7 +272,25 @@ def pad_plan(plan: SolverPlan, n_steps: int) -> SolverPlan:
     return dataclasses.replace(plan, coeffs=coeffs, ts=edge(plan.ts))
 
 
-def take_rows(plan: SolverPlan, rows, shardings=None) -> SolverPlan:
+@jax.jit
+def gather_plan_rows(coeffs, ts, idx):
+    """The device half of :func:`take_rows`: rows ``idx`` of a stacked
+    plan's coefficient leaves and grid, one program per (rows in, rows
+    out). The serving engine compiles it ahead for every pair it can
+    meet, so a warm engine's compaction compiles nothing."""
+    return {k: v[idx] for k, v in coeffs.items()}, ts[idx]
+
+
+@jax.jit
+def concat_plan_rows(coeffs, ts, add_coeffs, add_ts):
+    """The device half of :func:`join_rows`: a stacked plan's leaves with
+    the joiners' stacked leaves appended, the first rows untouched."""
+    return ({k: jnp.concatenate([v, add_coeffs[k]])
+             for k, v in coeffs.items()},
+            jnp.concatenate([ts, add_ts]))
+
+
+def take_rows(plan: SolverPlan, rows) -> SolverPlan:
     """Row-gather a stacked plan: keep requests ``rows`` (in that order).
 
     ``rows`` is a host-side index sequence into the leading request axis.
@@ -282,12 +300,6 @@ def take_rows(plan: SolverPlan, rows, shardings=None) -> SolverPlan:
     (the state half is :func:`repro.core.sampler.take_state_rows`). The
     result is still a stacked plan (even for a single surviving row) with the
     same signature family at the new, smaller batch.
-
-    ``shardings`` (a plan-shaped tree of ``jax.sharding.Sharding``, e.g. from
-    :func:`repro.sharding.rules.plan_specs` at the NEW batch size) makes the
-    gather *sharding-preserving*: the gathered leaves are committed to those
-    placements, so feeding the compacted plan to an AOT-compiled sharded
-    executor never triggers a resharding recompile mid-flight.
     """
     if not plan.stacked:
         raise ValueError("take_rows requires a stacked plan")
@@ -296,12 +308,8 @@ def take_rows(plan: SolverPlan, rows, shardings=None) -> SolverPlan:
     if idx.ndim != 1 or idx.size == 0:
         raise ValueError(f"rows must be a non-empty 1-D index sequence, got "
                          f"shape {idx.shape}")
-    out = dataclasses.replace(
-        plan, coeffs={k: v[idx] for k, v in plan.coeffs.items()},
-        ts=plan.ts[idx])
-    if shardings is not None:
-        out = jax.device_put(out, shardings)
-    return out
+    coeffs, ts = gather_plan_rows(plan.coeffs, plan.ts, idx)
+    return dataclasses.replace(plan, coeffs=coeffs, ts=ts)
 
 
 def _rowless_signature(plan: SolverPlan) -> tuple:
@@ -314,7 +322,7 @@ def _rowless_signature(plan: SolverPlan) -> tuple:
             tuple(plan.ts.shape[1:]), leaves)
 
 
-def join_rows(plan: SolverPlan, new_plans, shardings=None) -> SolverPlan:
+def join_rows(plan: SolverPlan, new_plans) -> SolverPlan:
     """Splice joiner rows onto a stacked plan's request axis.
 
     ``new_plans`` are UNSTACKED same-family plans; each is padded to the
@@ -329,13 +337,10 @@ def join_rows(plan: SolverPlan, new_plans, shardings=None) -> SolverPlan:
     the grown batch, so the serving executor cache is looked up, never
     re-traced, per (signature, batch, seq_len).
 
-    This is the plan half of join-at-compaction (continuous admission);
-    the state half is :func:`repro.core.sampler.join_state_rows`. Joined
-    rows start at step 0 while veterans continue at their own counts --
-    the executor's per-row ``k`` vector keeps both correct.
-
-    ``shardings`` (plan-shaped tree of shardings at the NEW batch) commits
-    the spliced leaves, mirroring :func:`take_rows`.
+    This is the plan half of continuous admission (joins into in-flight
+    groups); the state half is :func:`repro.core.sampler.join_state_rows`.
+    Joined rows start at step 0 while veterans continue at their own
+    counts -- the executor's per-row ``k`` vector keeps both correct.
     """
     if not plan.stacked:
         raise ValueError("join_rows splices rows onto a stacked plan")
@@ -357,15 +362,9 @@ def join_rows(plan: SolverPlan, new_plans, shardings=None) -> SolverPlan:
         raise ValueError(
             f"joiner rows are not of the stack's family:\n  "
             f"{_rowless_signature(plan)}\n  {_rowless_signature(add)}")
-    out = dataclasses.replace(
-        plan,
-        coeffs={k: jnp.concatenate([plan.coeffs[k], add.coeffs[k]])
-                for k in plan.coeffs},
-        ts=jnp.concatenate([plan.ts, add.ts]),
-        nfe=max(plan.nfe, add.nfe))
-    if shardings is not None:
-        out = jax.device_put(out, shardings)
-    return out
+    coeffs, ts = concat_plan_rows(plan.coeffs, plan.ts, add.coeffs, add.ts)
+    return dataclasses.replace(plan, coeffs=coeffs, ts=ts,
+                               nfe=max(plan.nfe, add.nfe))
 
 
 def inert_row(plan: SolverPlan) -> SolverPlan:

@@ -49,6 +49,7 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..kernels.ops import fused_ab_step as _fused_ab_step
 from .plan import SolverPlan
@@ -121,7 +122,26 @@ def init_state(plan: SolverPlan, x_T: Array, key: Optional[Array] = None) -> Sam
     return SamplerState(x=x_T, hist=hist, key=key, k=jnp.int32(0), err=err)
 
 
-def take_state_rows(state: SamplerState, rows, shardings=None) -> SamplerState:
+@jax.jit
+def gather_state_rows(state: SamplerState, idx) -> SamplerState:
+    """The device half of :func:`take_state_rows`, one program per (rows
+    in, rows out); the serving engine compiles it ahead for every pair it
+    can meet, as it does :func:`repro.core.plan.gather_plan_rows`."""
+    return SamplerState(x=state.x[idx], hist=state.hist[:, idx],
+                        key=state.key[idx], k=state.k, err=state.err[idx])
+
+
+@jax.jit
+def concat_state_rows(state: SamplerState, new: SamplerState) -> SamplerState:
+    """The device half of :func:`join_state_rows`."""
+    return SamplerState(x=jnp.concatenate([state.x, new.x], axis=0),
+                        hist=jnp.concatenate([state.hist, new.hist], axis=1),
+                        key=jnp.concatenate([state.key, new.key], axis=0),
+                        k=state.k,
+                        err=jnp.concatenate([state.err, new.err], axis=0))
+
+
+def take_state_rows(state: SamplerState, rows) -> SamplerState:
     """Row-gather a stacked solve's state: keep requests ``rows``, in order.
 
     Gathers ``x`` on axis 0, ``hist`` on axis 1 (its layout is
@@ -132,26 +152,16 @@ def take_state_rows(state: SamplerState, rows, shardings=None) -> SamplerState:
     remaining steps and noise draws it would have taken in the larger stack
     (or solo). This is the state half of mid-flight group compaction; the
     plan half is :func:`repro.core.plan.take_rows`.
-
-    ``shardings`` (a :class:`SamplerState` of ``jax.sharding.Sharding``, e.g.
-    built for the NEW batch size via :func:`repro.sharding.rules.state_specs`)
-    commits the gathered leaves to those placements, so a compacted state can
-    be fed straight to an AOT-compiled sharded executor without a resharding
-    recompile -- the sharded half of mid-flight compaction.
     """
-    idx = jnp.asarray(rows, dtype=jnp.int32)
-    if idx.ndim != 1 or idx.shape[0] == 0:
+    # repro: allow[RL001] rows is a host-side index list by contract (scheduler bookkeeping)
+    idx = np.asarray(rows, dtype=np.int32)
+    if idx.ndim != 1 or idx.size == 0:
         raise ValueError(f"rows must be a non-empty 1-D index sequence, got "
                          f"shape {idx.shape}")
-    out = SamplerState(x=state.x[idx], hist=state.hist[:, idx],
-                       key=state.key[idx], k=state.k, err=state.err[idx])
-    if shardings is not None:
-        out = jax.device_put(out, shardings)
-    return out
+    return gather_state_rows(state, idx)
 
 
-def join_state_rows(state: SamplerState, new: SamplerState,
-                    shardings=None) -> SamplerState:
+def join_state_rows(state: SamplerState, new: SamplerState) -> SamplerState:
     """Splice a fresh stacked state onto an in-flight stacked solve's rows.
 
     ``new`` is the joiners' own freshly-initialised stacked state (from
@@ -164,10 +174,7 @@ def join_state_rows(state: SamplerState, new: SamplerState,
     at 0, veterans at their own counts) each joiner reproduces its solo
     solve bitwise. ``k`` keeps the veteran state's counter (informational;
     serving tracks per-row counts host-side). This is the state half of
-    join-at-compaction; the plan half is :func:`repro.core.plan.join_rows`.
-
-    ``shardings`` (a :class:`SamplerState` of shardings at the NEW batch)
-    commits the spliced leaves, mirroring :func:`take_state_rows`.
+    continuous admission; the plan half is :func:`repro.core.plan.join_rows`.
     """
     if state.key.ndim != 2 or new.key.ndim != 2:
         raise ValueError("join_state_rows splices stacked states (per-request "
@@ -176,14 +183,7 @@ def join_state_rows(state: SamplerState, new: SamplerState,
         raise ValueError(f"history length mismatch: {state.hist.shape[0]} vs "
                          f"{new.hist.shape[0]} (joiners must share the "
                          "group's plan family)")
-    out = SamplerState(x=jnp.concatenate([state.x, new.x], axis=0),
-                       hist=jnp.concatenate([state.hist, new.hist], axis=1),
-                       key=jnp.concatenate([state.key, new.key], axis=0),
-                       k=state.k,
-                       err=jnp.concatenate([state.err, new.err], axis=0))
-    if shardings is not None:
-        out = jax.device_put(out, shardings)
-    return out
+    return concat_state_rows(state, new)
 
 
 # ----------------------------------------------------- request-axis sharding

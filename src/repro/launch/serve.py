@@ -254,8 +254,8 @@ def make_parser() -> argparse.ArgumentParser:
     ap.add_argument("--no-compaction", action="store_true")
     ap.add_argument("--no-join", action="store_true",
                     help="disable continuous admission (joining pending "
-                         "requests into in-flight groups at compaction "
-                         "boundaries)")
+                         "requests into in-flight groups of their bucket "
+                         "at every step boundary)")
     ap.add_argument("--seq-len-buckets", default=None,
                     help="comma-separated ascending edges (e.g. 32,64,128): "
                          "request seq_lens round up to a bucket edge so "

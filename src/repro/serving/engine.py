@@ -20,16 +20,19 @@ own PRNG key derived from its own ``Request.seed``, so samples are
 per-request reproducible regardless of batch composition, admission time,
 joining, or compaction.
 
-Admission is *continuous*: at every compaction boundary (a tick after rows
-retired, or a group carrying structural filler slots) pending same-bucket
-requests may **join** the surviving in-flight group instead of waiting for
-a fresh one -- joiner plan rows are padded to the group's grid and spliced
-(:func:`repro.core.plan.join_rows` / ``join_state_rows``), and the executor
-steps every row at its OWN count (a per-row ``k`` vector: joiners start at
-0 while veterans continue), so a warm ragged workload converges to a small
-fixed set of ``(family, batch, seq_len)`` executors that never drain and
-never recompile. A joiner whose grid exceeds the group's horizon forms a
-fresh group instead (extending the grid would change the signature).
+Admission is *continuous*: at every step boundary pending requests
+**join** an in-flight group of their bucket and priority that has room
+(``max_group`` minus its live rows; retired rows and structural filler are
+slots too) instead of waiting for a fresh one -- so a request that arrives
+while another of its bucket is in flight shares that group's row tiles
+rather than running a half-empty tile of its own. Joiner plan rows are
+padded to the group's grid and spliced onto the in-flight rows, which stay
+bitwise unmoved, and the executor steps every row at its OWN count (a
+per-row ``k`` vector: joiners start at 0 while veterans continue), so a
+warm ragged workload converges to a small fixed set of ``(family, batch,
+seq_len)`` executors that never drain and never recompile. A joiner whose
+grid exceeds the group's horizon forms a fresh group instead (extending the
+grid would change the signature).
 ``seq_len_buckets=(...)`` additionally rounds request lengths up to bucket
 edges (the solve carries the tail as extra latent positions; every emitted
 decode is masked back to the request's true ``seq_len``), so e.g. seq 48
@@ -70,9 +73,16 @@ and step indices (``k`` is traced as a PER-ROW vector, so the same
 executable serves uniform groups and post-join groups whose rows run at
 their own counts; pndm's warmup/tail split is a ``lax.cond``). Compaction looks its smaller batch up in the same cache, so a
 steady-state workload (e.g. the warm half of ``benchmarks/deis_serving``)
-runs with ZERO recompilation. ``Result.compile_s`` carries the trace+compile
-cost charged to the group that needed the executor; ``Result.latency_s`` is
-pure solve wall-time, so benchmark numbers are not poisoned by trace cost.
+runs with ZERO recompilation. The boundary pass's row moves -- the
+splice of joiners, the gather of survivors, the pick of finished rows to
+decode -- run the jitted device halves of ``join_rows`` / ``take_rows``
+and their state twins, one program per (rows in, rows out), compiled
+beside the executor of the batch they end at, so a warm-up that builds
+the executors has built them too (unsharded; under a mesh they compile on
+first use).
+``Result.compile_s`` carries the trace+compile cost charged to the group
+that needed the executor; ``Result.latency_s`` is pure solve wall-time, so
+benchmark numbers are not poisoned by trace cost.
 
 Callback contract.  ``serve(..., on_step=fn)`` invokes ``fn(StepEvent)``
 after every group step with the group's uids and progress; with
@@ -88,6 +98,7 @@ small-NFE advantage becomes throughput: serving capacity scales ~1/NFE.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import math
 import time
@@ -102,8 +113,9 @@ from ..configs.base import ModelConfig
 from ..core import cached_make_plan, get_timesteps
 from ..core import sampler as SAMPLER
 from ..core.adaptive import RetirePolicy
-from ..core.plan import (SolverPlan, inert_row, join_rows, pad_plan,
-                         solver_stages, stack_plans, take_rows)
+from ..core.plan import (SolverPlan, concat_plan_rows, gather_plan_rows,
+                         inert_row, join_rows, pad_plan, solver_stages,
+                         stack_plans, take_rows)
 from ..core.sde import SDE, VPSDE
 from ..diffusion import lm as DLM
 from ..models import transformer as T
@@ -391,12 +403,12 @@ class DiffusionServeEngine:
         (starvation aging). ``compaction``: retire finished rows mid-flight
         and re-pack survivors into a smaller cached batch bucket.
 
-        ``join``: continuous admission -- at every compaction boundary,
-        pending same-bucket requests are spliced into the surviving group
-        (retired rows become slots) instead of forming a fresh group, under
-        the same priority/EDF ordering as admission. Requires ``compaction``
-        (boundaries are where groups rebuild); with ``compaction=False``
-        the flag is inert.
+        ``join``: continuous admission -- at every step boundary, pending
+        requests are spliced into an in-flight group of their bucket and
+        priority that has room (retired rows become slots) instead of
+        forming a fresh group, under the same priority/EDF ordering as
+        admission. Requires ``compaction`` (boundaries are where groups
+        rebuild); with ``compaction=False`` the flag is inert.
 
         ``seq_len_buckets``: ascending edge lengths; a request's seq_len
         rounds UP to the first edge that fits, the solve runs at the bucket
@@ -705,12 +717,73 @@ class DiffusionServeEngine:
                                                 state_sh, row_sh),
                              out_shardings=state_sh)
         with self.tracer.span("compile"):
-            compiled = jitted.lower(self._params_exec, plan, rows,
-                                    state, rows).compile()
+            lowered = jitted.lower(self._params_exec, plan, rows, state, rows)
+            # the row moves ending at this batch are lowered while the step
+            # program compiles, and compile beside it: XLA's compile
+            # releases the GIL
+            with concurrent.futures.ThreadPoolExecutor(8) as pool:
+                step = pool.submit(lowered.compile)
+                moves = [] if self.mesh is not None \
+                    else [pool.submit(lo.compile)
+                          for lo in self._row_moves(plan, state)]
+                compiled = step.result()
+                for f in moves:
+                    f.result()
         compile_s = time.perf_counter() - t0
         self._m_compile_s.inc(compile_s)
         self._compiled[key_] = compiled
         return compiled, compile_s
+
+    def _row_moves(self, plan: SolverPlan, state) -> list:
+        """The row moves that end at this batch of ``R`` rows, lowered: the
+        splice into it from every smaller count (a join) and the gather
+        into it from every larger count (a compaction, or the pick of
+        finished rows to decode), each a plan half and a state half. Once
+        they are compiled, a warm engine's boundary pass compiles nothing,
+        however the groups join and shrink. Unsharded only: placed inputs
+        key other programs."""
+        r = state.x.shape[0]
+
+        def at(n):          # (coeffs, ts, state) shapes at n rows
+            def sds(a, axis=0):
+                shape = a.shape if axis is None \
+                    else a.shape[:axis] + (n,) + a.shape[axis + 1:]
+                return jax.ShapeDtypeStruct(shape, a.dtype,
+                                            weak_type=a.weak_type)
+            return ({k: sds(v) for k, v in plan.coeffs.items()},
+                    sds(plan.ts),
+                    SAMPLER.SamplerState(x=sds(state.x),
+                                         hist=sds(state.hist, 1),
+                                         key=sds(state.key),
+                                         k=sds(state.k, None),
+                                         err=sds(state.err)))
+        out = []
+        for n in range(1, r):
+            (c, t, st), (ac, at_, ast) = at(n), at(r - n)
+            out += [concat_plan_rows.lower(c, t, ac, at_),
+                    SAMPLER.concat_state_rows.lower(st, ast)]
+        idx = jax.ShapeDtypeStruct((r,), jnp.int32)
+        for n in range(r + 1, self._chunk_cap + 1):
+            c, t, st = at(n)
+            out += [gather_plan_rows.lower(c, t, idx),
+                    SAMPLER.gather_state_rows.lower(st, idx)]
+        return out
+
+    def _place(self, plan: SolverPlan, state) -> tuple:
+        """(plan, state) committed to the mesh's request-axis placement
+        (as they are, unsharded)."""
+        plan_sh, state_sh = self._shardings(plan, state)
+        if plan_sh is None:
+            return plan, state
+        return jax.device_put(plan, plan_sh), jax.device_put(state, state_sh)
+
+    def _decode_rows(self, g: _Group, idx: list) -> np.ndarray:
+        """Tokens of rows ``idx`` of ``g``: its whole iterate when ``idx``
+        is every row, else the rows' gather."""
+        x = g.state.x if idx == list(range(len(g.rows))) \
+            else SAMPLER.take_state_rows(g.state, idx).x
+        # repro: allow[RL001] finished rows leave the device here by design
+        return np.asarray(DLM.decode_tokens(self._params_exec, self.cfg, x))
 
     # -------------------------------------------------------- scheduling
     def _bucket_len(self, seq_len: int) -> int:
@@ -724,8 +797,8 @@ class DiffusionServeEngine:
 
     def submit(self, request: Request) -> None:
         """Validate and enqueue; the request is admitted at the next tick --
-        into a fresh group, or spliced into an in-flight one at a compaction
-        boundary. Validation (unknown solver, ddim_eta without eta) raises
+        into a fresh group, or spliced into an in-flight one of its bucket.
+        Validation (unknown solver, ddim_eta without eta) raises
         HERE, before the request enters the queue, so a bad request can never
         strand already-queued work mid-admission. The submit timestamp
         anchors the request's absolute deadline (``deadline_s`` is relative
@@ -867,9 +940,7 @@ class DiffusionServeEngine:
             hit = [i for i, m in zip(cand, mask) if m]
             if not hit:
                 continue
-            # repro: allow[RL001] retiring rows leave the device here by design
-            toks = np.asarray(DLM.decode_tokens(
-                self._params_exec, self.cfg, g.state.x[jnp.asarray(hit)]))
+            toks = self._decode_rows(g, hit)
             for j, i in enumerate(hit):
                 r = g.rows[i]
                 r.done = True
@@ -895,13 +966,13 @@ class DiffusionServeEngine:
         Two phases, both ordered by the same urgency key (priority desc,
         deadline asc):
 
-        1. *Boundary pass* (``compaction`` on): every group carrying
-           retired/filler rows rebuilds before its next step -- pending
-           same-bucket requests whose grids fit the group's horizon JOIN it
-           (retired rows become slots; ``join`` on), and what cannot be
-           refilled compacts down to its survivors. Groups are visited in
-           ``_select``'s urgency order, so the most urgent in-flight work
-           gets the most urgent joiners.
+        1. *Boundary pass* (``compaction`` on): every in-flight group is
+           offered the pending requests of its bucket and priority whose
+           grids fit its horizon, and they JOIN it up to ``max_group`` live
+           rows (retired rows become slots; ``join`` on). A group carrying
+           retired/filler rows that nothing refills compacts down to its
+           survivors. Groups are visited in ``_select``'s urgency order, so
+           the most urgent in-flight work gets the most urgent joiners.
         2. *Fresh groups*: remaining pending requests bucket by
            ``(plan.family, bucketed seq_len)`` -- any mix of solver names
            AND NFE budgets whose plans pad+stack is one solve (ragged
@@ -940,13 +1011,13 @@ class DiffusionServeEngine:
                                        self._abs_deadline(it.req, it.t_sub)))
         if self.compaction:
             for g in sorted(self._active, key=self._group_key):
-                if not any(r.done for r in g.rows):
-                    continue
                 take = (self._joiners(g, buckets.get(g.bucket))
                         if self.join else [])
                 if take:
                     with self.tracer.span("join"):
                         self._join_group(g, take, now)
+                    continue
+                if not any(r.done for r in g.rows):
                     continue
                 live = [i for i, r in enumerate(g.rows) if not r.done]
                 keep = self._compact_target(g, live)
@@ -1000,10 +1071,7 @@ class DiffusionServeEngine:
                 valid_lens=[p.req.seq_len for p in chunk]
                 + [s_len] * n_fill)
         fn, compile_s = self._executor(sig, plan, state)
-        plan_sh, state_sh = self._shardings(plan, state)
-        if plan_sh is not None:
-            plan = jax.device_put(plan, plan_sh)
-            state = jax.device_put(state, state_sh)
+        plan, state = self._place(plan, state)
         reqs = [p.req for p in chunk]
         self._arrivals += 1
         self._active.append(_Group(
@@ -1020,14 +1088,26 @@ class DiffusionServeEngine:
         taken from the front up to the group's free slots, skipping any
         whose grid exceeds the group's horizon (they form fresh groups
         instead -- extending the grid would change the signature and
-        recompile). Empty when nothing can join (the caller compacts)."""
-        cap = self._chunk_cap - sum(not r.done for r in g.rows)
+        recompile) or whose priority differs from the group's live rows'
+        (a joiner would lift or sink the group under ``steps_per_tick``).
+        Where the group or a joiner carries a deadline, joiners only fill
+        the free slots of the live rows' last row tile: one tile more would
+        slow every step of the deadline row. Empty when nothing can join."""
+        live = [r for r in g.rows if not r.done]
+        cap = self._chunk_cap - len(live)
         if not cands or cap <= 0:
             return []
+        prio = max(r.req.priority for r in live)
+        deadline = min(r.deadline for r in live)
+        room = -len(live) % DLM.ROW_TILE
         take, rest = [], []
         for p in cands:
-            if len(take) < cap and p.plan.n_steps <= g.plan.n_steps:
+            due = self._abs_deadline(p.req, p.t_sub)
+            if len(take) < cap and p.plan.n_steps <= g.plan.n_steps \
+                    and p.req.priority == prio \
+                    and (len(take) < room or math.isinf(min(deadline, due))):
                 take.append(p)
+                deadline = min(deadline, due)
             else:
                 rest.append(p)
         cands[:] = rest
@@ -1035,22 +1115,22 @@ class DiffusionServeEngine:
 
     def _join_group(self, g: _Group, take: list, now: float) -> None:
         """Splice the pending requests ``take`` (see :meth:`_joiners`) into
-        ``g`` at a compaction boundary. The rebuilt batch
+        ``g`` at a step boundary. The rebuilt batch
         keeps the surviving rows in their original relative order, each
-        carried whole and bitwise-unmoved (``take_rows`` of the survivors,
-        then ``join_rows`` appending the padded joiners), rounds up to a
+        carried whole and bitwise-unmoved (``take_rows`` of the survivors
+        when rows retired, then ``join_rows`` appending the padded
+        joiners), rounds up to a
         data-axis multiple reusing retired rows as slots before allocating
         inert filler, and stays within ``max_group``. Joiner
         rows record ``k0 = g.k`` (their steps count from THIS tick) and
         ``solve_s0`` (their latency excludes the group's past)."""
         live = [i for i, r in enumerate(g.rows) if not r.done]
         keep, n_inert = self._round_keep(g, live, len(take))
-        plan_sh, state_sh = self._shardings(g.plan, g.state)
         if keep != list(range(len(g.rows))):
             # the intermediate gather may not be a data-axis multiple (e.g.
             # 8 rows -> 4 survivors before 4 joiners splice back to 8), so
-            # it stays uncommitted; only the FINAL spliced batch -- always
-            # a multiple -- is placed (join_rows/join_state_rows below)
+            # it stays unplaced; only the FINAL spliced batch -- always a
+            # multiple -- is placed (after join_rows/join_state_rows below)
             g.plan = take_rows(g.plan, keep)
             g.state = SAMPLER.take_state_rows(g.state, keep)
             g.rows = [g.rows[i] for i in keep]
@@ -1077,9 +1157,9 @@ class DiffusionServeEngine:
                 prior_std=self.sde.prior_std(),
                 valid_lens=[p.req.seq_len for p in take]
                 + [g.seq_len] * n_inert)
-        g.plan = join_rows(g.plan, padded, shardings=plan_sh)
-        g.state = SAMPLER.join_state_rows(g.state, add_state,
-                                          shardings=state_sh)
+        g.plan, g.state = self._place(
+            join_rows(g.plan, padded),
+            SAMPLER.join_state_rows(g.state, add_state))
         g.rows += new_rows
         live_rows = [r for r in g.rows if not r.done]
         g.n_steps = max(r.k0 + r.n_steps for r in live_rows)
@@ -1150,9 +1230,8 @@ class DiffusionServeEngine:
         retired urgent row's priority/deadline does not keep preempting
         other groups on behalf of best-effort leftovers."""
         self._m_compactions.inc()
-        plan_sh, state_sh = self._shardings(g.plan, g.state)
-        g.plan = take_rows(g.plan, keep, shardings=plan_sh)
-        g.state = SAMPLER.take_state_rows(g.state, keep, shardings=state_sh)
+        g.plan, g.state = self._place(take_rows(g.plan, keep),
+                                      SAMPLER.take_state_rows(g.state, keep))
         g.rows = [g.rows[i] for i in keep]
         live = []
         for r in g.rows:
@@ -1194,7 +1273,7 @@ class DiffusionServeEngine:
 
     def tick(self, *, on_step=None, stream_decode: bool = False) -> list[Result]:
         """One scheduler tick: admit pending requests (joining in-flight
-        groups at compaction boundaries, else forming fresh ones), advance
+        groups of their bucket, else forming fresh ones), advance
         the selected groups one solver step each, emit Results for rows
         that finished.
 
@@ -1302,10 +1381,7 @@ class DiffusionServeEngine:
                     # decode already exists (ragged groups would otherwise
                     # pay one full-batch decode per distinct member NFE)
                     new_toks = (stream_toks[newly] if stream_toks is not None
-                                # repro: allow[RL001] finished rows leave the device here by design
-                                else np.asarray(DLM.decode_tokens(
-                                    self._params_exec, self.cfg,
-                                    g.state.x[jnp.asarray(newly)])))
+                                else self._decode_rows(g, newly))
                     for j, i in enumerate(newly):
                         row = g.rows[i]
                         row.done = True

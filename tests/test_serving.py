@@ -285,20 +285,29 @@ def test_compaction_reduces_wasted_row_steps(diff_setup):
 def test_deadline_request_preempts_older_work(diff_setup):
     """EDF under a throttled scheduler (steps_per_tick=1): a deadline-tight
     request submitted AFTER an in-flight best-effort group is stepped ahead
-    of it every tick until it completes -- and the old work still drains."""
+    of it every tick until it completes -- and the old work still drains.
+    join=False keeps B in a group of its own; with joins on, B (same bucket
+    and priority) rides in A's group, which its deadline then leads."""
     params, cfg = diff_setup
-    eng = DiffusionServeEngine(params, cfg, steps_per_tick=1,
-                               aging_ticks=1000)
-    events = []
-    eng.submit(Request(uid=0, seq_len=16, nfe=6, solver="tab1", seed=0))
-    done = eng.tick(on_step=events.append)          # A in flight, k=1
-    eng.submit(Request(uid=1, seq_len=16, nfe=3, solver="tab1", seed=1,
-                       deadline_s=0.05))
-    while eng.busy:
-        done += eng.tick(on_step=events.append)
-    # B (deadline) takes every tick from admission until it finishes
-    assert [e.uids[0] for e in events] == [0, 1, 1, 1, 0, 0, 0, 0, 0]
-    assert [r.uid for r in done] == [1, 0]          # B finishes first
+    for join in (False, True):
+        eng = DiffusionServeEngine(params, cfg, steps_per_tick=1,
+                                   aging_ticks=1000, join=join)
+        events = []
+        eng.submit(Request(uid=0, seq_len=16, nfe=6, solver="tab1", seed=0))
+        done = eng.tick(on_step=events.append)          # A in flight, k=1
+        eng.submit(Request(uid=1, seq_len=16, nfe=3, solver="tab1", seed=1,
+                           deadline_s=0.05))
+        while eng.busy:
+            done += eng.tick(on_step=events.append)
+        if join:
+            # one group: B steps every tick from admission, A with it
+            assert [e.uids for e in events] == [(0,)] + [(0, 1)] * 3 \
+                + [(0,)] * 2
+            assert eng.joined_requests == 1
+        else:
+            # B (deadline) takes every tick from admission until it finishes
+            assert [e.uids[0] for e in events] == [0, 1, 1, 1, 0, 0, 0, 0, 0]
+        assert [r.uid for r in done] == [1, 0]      # B finishes first
 
 
 def test_compaction_recomputes_group_urgency(diff_setup):
@@ -485,6 +494,134 @@ def test_joined_request_streams_own_progress(diff_setup):
     prog = [dict(zip(e.uids, e.row_k)) for e in events]
     assert [p.get(2) for p in prog] == [None, None, None, 1, 2, 3, 4]
     assert [p[1] for p in prog] == [1, 2, 3, 4, 5, 6, 7]   # veteran unmoved
+
+
+@pytest.mark.parametrize("uid,seq_len,priority,joins", [
+    (1, 12, 0, True),        # same bucket (16) and priority: shares the tile
+    (2, 8, 0, False),        # the other bucket: a group of its own
+    (3, 16, 1, False),       # another priority: a group of its own
+], ids=["same_bucket", "other_bucket", "other_priority"])
+def test_inflight_join_by_bucket_and_priority(diff_setup, uid, seq_len,
+                                              priority, joins):
+    """A request that arrives while a lone request of its bucket is in
+    flight -- no row retired -- joins that group at the next step boundary;
+    one of the other bucket or another priority forms its own. Either way
+    both samples equal their solo solves bitwise."""
+    params, cfg = diff_setup
+    eng = DiffusionServeEngine(params, cfg, seq_len_buckets=(8, 16))
+    first = Request(uid=0, seq_len=16, nfe=4, solver="tab2", seed=1)
+    late = Request(uid=uid, seq_len=seq_len, nfe=4, solver="tab2", seed=2,
+                   priority=priority)
+    eng.submit(first)
+    out = eng.tick() + eng.tick()            # the lone request is 2 steps in
+    eng.submit(late)
+    out += eng.tick()
+    assert eng.joined_requests == int(joins)
+    assert len(eng._active) == (1 if joins else 2)
+    while eng.busy:
+        out += eng.tick()
+    got = {r.uid: r for r in out}
+    assert got[uid].nfe == 4
+    solo = DiffusionServeEngine(params, cfg, seq_len_buckets=(8, 16))
+    for q in (first, late):
+        np.testing.assert_array_equal(solo.serve([q])[0].tokens,
+                                      got[q.uid].tokens)
+
+
+@pytest.mark.parametrize("n_first,first_due,late_due,joins", [
+    (1, 60.0, None, True),     # a lone deadline row: the joiner fills its tile
+    (2, 60.0, None, False),    # the tile is full: a third row would add one
+    (2, None, 60.0, False),    # nor may a deadline joiner open one
+    (2, None, None, True),     # best-effort rows: the joiner opens a tile
+], ids=["deadline_fills_tile", "deadline_full_tile", "deadline_joiner",
+        "best_effort"])
+def test_join_adds_no_tile_to_a_deadline_row(diff_setup, n_first, first_due,
+                                             late_due, joins):
+    """Under ``enforce_deadlines`` a join never makes a deadline row's steps
+    run one row tile more: where the group or the joiner carries a
+    deadline, a joiner only fills a free slot of the live rows' last tile,
+    else it forms its own group. No deadline is missed either way."""
+    params, cfg = diff_setup
+    eng = DiffusionServeEngine(params, cfg, enforce_deadlines=True)
+    for j in range(n_first):
+        eng.submit(Request(uid=j, seq_len=16, nfe=4, solver="tab2", seed=j,
+                           deadline_s=first_due))
+    out = eng.tick()
+    eng.submit(Request(uid=9, seq_len=16, nfe=4, solver="tab2", seed=9,
+                       deadline_s=late_due))
+    out += eng.tick()
+    assert eng.joined_requests == int(joins)
+    assert len(eng._active) == (1 if joins else 2)
+    while eng.busy:
+        out += eng.tick()
+    assert sorted(r.uid for r in out) == list(range(n_first)) + [9]
+    assert not any(r.deadline_exceeded for r in out)
+
+
+def test_warm_engine_joins_without_compiling(diff_setup):
+    """Warmed the way the chip benchmark warms a cell -- per bucket, groups
+    of 1..max_group admitted, stepped once and dropped, then the decode of
+    every finished-row count -- an engine replays in-flight joins,
+    compactions and partial finishes without compiling a program: every row
+    move of the boundary pass was compiled with the executor it ends at.
+    Buckets 10/20 are this test's own, so no other test's programs warm
+    them."""
+    from jax import monitoring
+
+    params, cfg = diff_setup
+    eng = DiffusionServeEngine(params, cfg, max_group=3,
+                               seq_len_buckets=(10, 20))
+    lens = {10: [7, 10], 20: [13, 20]}
+    for s_len, ls in lens.items():
+        for r in range(1, eng.max_group + 1):
+            for j in range(r):
+                eng.submit(Request(uid=-1 - j, seq_len=ls[j % 2], nfe=4,
+                                   solver="tab2", seed=j))
+            eng.tick()
+            eng.reset()
+        for r in range(1, eng.max_group + 1):      # rows all at the edge
+            for j in range(r):
+                eng.submit(Request(uid=-1 - j, seq_len=s_len, nfe=4,
+                                   solver="tab2", seed=j))
+            eng.tick()
+            eng.reset()
+        x = jnp.zeros((eng.max_group, s_len, cfg.d_model), jnp.float32)
+        for r in range(1, eng.max_group + 1):
+            np.asarray(DLM.decode_tokens(
+                params, cfg, x[:r][jnp.asarray(list(range(r)))]))
+
+    # (arrival tick, seq_len): joins into groups with no retired row, a join
+    # into a group with one, compactions, and rows finishing apart
+    arrivals = [(0, 20), (1, 13), (2, 20), (2, 7), (3, 10), (4, 13),
+                (4, 20), (6, 10), (9, 13)]
+    compiled = []
+
+    def on_compile(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        out, t, i = [], 0, 0
+        while i < len(arrivals) or eng.busy:
+            while i < len(arrivals) and arrivals[i][0] <= t:
+                eng.submit(Request(uid=i, seq_len=arrivals[i][1], nfe=4,
+                                   solver="tab2", seed=10 + i))
+                i += 1
+            out += eng.tick()
+            t += 1
+    finally:
+        monitoring.unregister_event_duration_listener(on_compile)
+    assert compiled == []
+    assert len(out) == len(arrivals)
+    assert eng.joined_requests >= 4
+    assert eng.metrics.counter("serve_compactions_total").value >= 1
+    solo = DiffusionServeEngine(params, cfg, seq_len_buckets=(10, 20))
+    got = {r.uid: r.tokens for r in out}
+    for uid in (1, 4, 8):
+        q = Request(uid=uid, seq_len=arrivals[uid][1], nfe=4, solver="tab2",
+                    seed=10 + uid)
+        np.testing.assert_array_equal(solo.serve([q])[0].tokens, got[uid])
 
 
 def test_seq_len_buckets_share_executor(diff_setup):
