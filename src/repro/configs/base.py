@@ -17,11 +17,26 @@ from typing import Any, Optional
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts. ``num_experts`` is the router's width. The capacity
+    path (GShard dispatch, the training substrate's) holds every expert and
+    drops tokens past capacity. With ``dropless`` the layer routes over all
+    ``num_experts`` and computes, with no capacity, the part of the result
+    that the experts held on this chip give: ids ``[expert_offset,
+    expert_offset + held)``, one chip's share of an expert-parallel
+    deployment."""
     num_experts: int = 8
     top_k: int = 2
     capacity_factor: float = 1.25
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
+    expert_d_ff: int = 0        # one expert's width; 0 -> ModelConfig.d_ff
+    dropless: bool = False      # held-expert routing with no capacity
+    experts_held: int = 0       # experts on this chip; 0 -> num_experts
+    expert_offset: int = 0      # global id of the first held expert
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,6 +68,7 @@ class ModelConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     logit_softcap: float = 0.0  # grok/gemma2-style tanh softcap, 0 = off
+    qk_norm: bool = False     # RMSNorm of q and k over head_dim before RoPE
     # MoE / SSM / hybrid
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -88,6 +104,11 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.resolved_head_dim
+
+    @property
+    def expert_width(self) -> int:
+        return (self.moe.expert_d_ff if self.moe is not None else 0) \
+            or self.d_ff
 
     def is_attn_layer(self, i: int) -> bool:
         if self.arch_type == "ssm":
@@ -127,7 +148,12 @@ class ModelConfig:
         if self.sliding_window:
             kw["sliding_window"] = 16
         if self.moe is not None:
-            kw["moe"] = dataclasses.replace(self.moe, num_experts=min(self.moe.num_experts, 4))
+            n_exp = min(self.moe.num_experts, 4)
+            kw["moe"] = dataclasses.replace(
+                self.moe, num_experts=n_exp, top_k=min(self.moe.top_k, n_exp),
+                expert_d_ff=min(self.moe.expert_d_ff, 512),
+                experts_held=min(self.moe.experts_held, n_exp),
+                expert_offset=0)
         if self.ssm is not None:
             kw["ssm"] = dataclasses.replace(self.ssm, state_dim=16, head_dim=16, chunk_size=16)
         return self.with_(**kw)
@@ -156,7 +182,7 @@ INPUT_SHAPES = {
 ARCH_IDS = [
     "whisper_tiny", "h2o_danube_3_4b", "paligemma_3b", "mixtral_8x7b",
     "grok_1_314b", "mamba2_2p7b", "glm4_9b", "gemma_2b", "granite_3_8b",
-    "jamba_1p5_large", "cifar10_scorenet",
+    "jamba_1p5_large", "cifar10_scorenet", "sdar_30b_a3b",
 ]
 
 
@@ -166,6 +192,12 @@ def get_config(arch: str, **overrides) -> ModelConfig:
     mod = importlib.import_module(f"repro.configs.{arch}")
     cfg: ModelConfig = mod.get_config()
     if overrides:
+        # a dict given for a nested group (``moe``, ``ssm``) replaces the
+        # fields it names and keeps the rest
+        overrides = {k: dataclasses.replace(getattr(cfg, k), **v)
+                     if isinstance(v, dict)
+                     and dataclasses.is_dataclass(getattr(cfg, k)) else v
+                     for k, v in overrides.items()}
         cfg = cfg.with_(**overrides)
     return cfg
 

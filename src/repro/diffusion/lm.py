@@ -65,6 +65,27 @@ def diffusion_loss(params, cfg: ModelConfig, sde: SDE, tokens, key, *,
     return loss, {"loss": loss, "mse": mse, "ce": ce}
 
 
+def _eps_forward(params, cfg: ModelConfig, x, t, *, prefix=None,
+                 frames=None, use_pallas: bool = False, unroll: int = 1,
+                 valid_len=None):
+    """(eps, held-expert assignments per row or None) at x (B, S, D)."""
+    b = x.shape[0]
+    t_b = jnp.broadcast_to(t, (b,)).astype(jnp.float32)
+    xin = x
+    vl = valid_len
+    if cfg.arch_type == "vlm" and prefix is not None:
+        xin = jnp.concatenate([prefix.astype(x.dtype), x], axis=1)
+        if vl is not None:
+            vl = vl + prefix.shape[1]   # prefix positions are all valid
+    out = T.forward(params, cfg, embeds=xin, t_cond=t_b, mode="train",
+                    causal=False, frames=frames, use_pallas=use_pallas,
+                    unroll=unroll, valid_len=vl)
+    eps = out["eps"].astype(x.dtype)
+    if cfg.arch_type == "vlm" and prefix is not None:
+        eps = eps[:, prefix.shape[1]:]
+    return eps, out.get("moe_held")
+
+
 def make_eps_fn(params, cfg: ModelConfig, *, prefix=None, frames=None,
                 use_pallas: bool = False, unroll: int = 1, valid_len=None):
     """eps_theta(x, t) closure for the DEIS solvers; x: (B, S, D), t scalar.
@@ -73,21 +94,9 @@ def make_eps_fn(params, cfg: ModelConfig, *, prefix=None, frames=None,
     batches -- threaded to attention so a row's denoising trajectory does
     not depend on the bucketed tail padding."""
     def eps_fn(x, t):
-        b = x.shape[0]
-        t_b = jnp.broadcast_to(t, (b,)).astype(jnp.float32)
-        xin = x
-        vl = valid_len
-        if cfg.arch_type == "vlm" and prefix is not None:
-            xin = jnp.concatenate([prefix.astype(x.dtype), x], axis=1)
-            if vl is not None:
-                vl = vl + prefix.shape[1]   # prefix positions are all valid
-        out = T.forward(params, cfg, embeds=xin, t_cond=t_b, mode="train",
-                        causal=False, frames=frames, use_pallas=use_pallas,
-                        unroll=unroll, valid_len=vl)
-        eps = out["eps"].astype(x.dtype)
-        if cfg.arch_type == "vlm" and prefix is not None:
-            eps = eps[:, prefix.shape[1]:]
-        return eps
+        return _eps_forward(params, cfg, x, t, prefix=prefix, frames=frames,
+                            use_pallas=use_pallas, unroll=unroll,
+                            valid_len=valid_len)[0]
     return eps_fn
 
 
@@ -97,10 +106,15 @@ def make_eps_fn(params, cfg: ModelConfig, *, prefix=None, frames=None,
 ROW_TILE = 2
 
 
-def make_tiled_eps_fn(params, cfg: ModelConfig, *, valid_len, mesh=None):
+def make_tiled_eps_fn(params, cfg: ModelConfig, *, valid_len, mesh=None,
+                      held_counts: list | None = None):
     """:func:`make_eps_fn` run one tile of :data:`ROW_TILE` rows at a time:
     the serving executors' eps, for ``(R, S, D)`` groups with a per-row
     ``(R,)`` ``valid_len``.
+
+    ``held_counts``: a list to which each call appends the ``(R,)``
+    token-expert pairs a dropless MoE's held experts computed per row, at
+    its valid positions over every layer (nothing for other models).
 
     A row's eps must not depend on how many rows share the call (a served
     request decodes bitwise the same solo, stacked or sharded). XLA on a
@@ -111,6 +125,9 @@ def make_tiled_eps_fn(params, cfg: ModelConfig, *, valid_len, mesh=None):
     group, and a row's bits do not depend on its place in the tile. Under
     a request-axis ``mesh`` each device loops over its own rows
     (``shard_map``), so no row is computed twice."""
+    counting = held_counts is not None and cfg.moe is not None \
+        and cfg.moe.dropless
+
     def rows(params, x, t, vl):
         # The group is padded with copies of its first row to whole tiles
         # plus one spare tile, and the trip count is read from the data
@@ -124,25 +141,40 @@ def make_tiled_eps_fn(params, cfg: ModelConfig, *, valid_len, mesh=None):
                       for a in (x, t, vl))
         n = (jnp.sum(vl >= 0, dtype=jnp.int32) + ROW_TILE - 1) // ROW_TILE
 
-        def one(i, out):
+        def one(i, carry):
             def tile(a):
                 return jax.lax.dynamic_slice_in_dim(a, i * ROW_TILE, ROW_TILE)
-            e = make_eps_fn(params, cfg, valid_len=tile(vp))(tile(xp),
-                                                             tile(tp))
-            return jax.lax.dynamic_update_slice_in_dim(out, e, i * ROW_TILE,
-                                                       0)
-        return jax.lax.fori_loop(0, n, one, jnp.zeros_like(xp))[:r]
+
+            def put(a, v):
+                return jax.lax.dynamic_update_slice_in_dim(a, v, i * ROW_TILE,
+                                                           0)
+            vl_i = tile(vp)
+            e, h = _eps_forward(params, cfg, tile(xp), tile(tp),
+                                valid_len=vl_i)
+            if counting:
+                return put(carry[0], e), put(carry[1], h)
+            return put(carry, e)
+        init = jnp.zeros_like(xp)
+        if counting:
+            init = (init, jnp.zeros(vp.shape, jnp.int32))
+        out = jax.lax.fori_loop(0, n, one, init)
+        return (out[0][:r], out[1][:r]) if counting else (out[:r], None)
 
     def eps_fn(x, t):
         t_b = jnp.broadcast_to(t, x.shape[:1]).astype(jnp.float32)
         if mesh is None:
-            return rows(params, x, t_b, valid_len)
-        from ..sharding.rules import request_axis_spec
-        spec = request_axis_spec(x, mesh, 0)
-        row = request_axis_spec(t_b, mesh, 0)
-        return jax.shard_map(rows, mesh=mesh,
-                             in_specs=(P(), spec, row, row),
-                             out_specs=spec)(params, x, t_b, valid_len)
+            eps, held = rows(params, x, t_b, valid_len)
+        else:
+            from ..sharding.rules import request_axis_spec
+            spec = request_axis_spec(x, mesh, 0)
+            row = request_axis_spec(t_b, mesh, 0)
+            eps, held = jax.shard_map(
+                rows, mesh=mesh, in_specs=(P(), spec, row, row),
+                out_specs=(spec, row if counting else None))(
+                    params, x, t_b, valid_len)
+        if counting:
+            held_counts.append(held)
+        return eps
     return eps_fn
 
 

@@ -1,9 +1,10 @@
 """Per-kernel backend capability: where each Pallas kernel has a compiled
 lowering, and therefore what ``interpret=None`` should resolve to.
 
-All three kernels are written against the generic Pallas API (no ``pltpu``
-scratch shapes, no cross-grid-step state carry). Their Mosaic lowering is
-checked at real widths for a described TPU v5e by
+The first three kernels are written against the generic Pallas API (no
+``pltpu`` scratch shapes, no cross-grid-step state carry); ``moe_experts``
+uses Mosaic's scalar prefetch and lowers on a TPU only. Their Mosaic
+lowering is checked at real widths for a described TPU v5e by
 ``tests/test_tpu_compile.py``; the Triton (GPU) lowering is the same
 generic Pallas but no test compiles it. Only the CPU backend has no
 compiled lowering and runs the Python interpreter. The table is per kernel
@@ -20,6 +21,8 @@ _LOWERS: dict[str, tuple[str, ...]] = {
     "deis_step": ("tpu", "gpu", "cuda", "rocm"),
     "flash_attention": ("tpu", "gpu", "cuda", "rocm"),
     "ssd_scan": ("tpu", "gpu", "cuda", "rocm"),
+    # scalar-prefetched block indices: Mosaic only
+    "moe_experts": ("tpu",),
 }
 
 

@@ -1,5 +1,6 @@
-"""Backbone building blocks: norms, RoPE, attention (GQA/MQA/SWA, KV cache),
-GLU MLPs, MoE (GShard-style capacity dispatch), time conditioning.
+"""Backbone building blocks: norms, RoPE, attention (GQA/MQA/SWA, KV cache,
+optional per-head q/k norm), GLU MLPs, MoE (GShard-style capacity dispatch,
+or dropless routing over the experts held on this chip), time conditioning.
 
 Pure functions over parameter pytrees (no flax). All matmuls via einsum with
 ``preferred_element_type=float32`` accumulation when inputs are bf16.
@@ -101,12 +102,17 @@ def init_attention(key, cfg: ModelConfig, dtype):
     d, qd, kvd = cfg.d_model, cfg.q_dim, cfg.kv_dim
     ks = jax.random.split(key, 4)
     s = 1.0 / math.sqrt(d)
-    return {
+    p = {
         "wq": (jax.random.normal(ks[0], (d, qd)) * s).astype(dtype),
         "wk": (jax.random.normal(ks[1], (d, kvd)) * s).astype(dtype),
         "wv": (jax.random.normal(ks[2], (d, kvd)) * s).astype(dtype),
         "wo": (jax.random.normal(ks[3], (qd, d)) * s / math.sqrt(2 * cfg.n_layers)).astype(dtype),
     }
+    if cfg.qk_norm:
+        hd = cfg.resolved_head_dim
+        p["q_norm"] = jnp.zeros((hd,), dtype)
+        p["k_norm"] = jnp.zeros((hd,), dtype)
+    return p
 
 
 def attention(params, cfg: ModelConfig, x, positions, *, causal=True,
@@ -131,6 +137,9 @@ def attention(params, cfg: ModelConfig, x, positions, *, causal=True,
     if kv_override is None:
         k = matmul(x, params["wk"]).reshape(b, s, cfg.n_kv_heads, hd)
         v = matmul(x, params["wv"]).reshape(b, s, cfg.n_kv_heads, hd)
+        if cfg.qk_norm:
+            q = rms_norm(q, params["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, params["k_norm"], cfg.norm_eps)
         cos, sin = rope_frequencies(hd, positions, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -238,15 +247,63 @@ def mlp(params, cfg: ModelConfig, x):
 
 # ---------------------------------------------------------------------- MoE
 def init_moe(key, cfg: ModelConfig, dtype):
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.moe.num_experts
+    """The router over all ``num_experts``, and the stacks of the experts
+    held here (every expert, unless ``experts_held`` says otherwise)."""
+    d, f = cfg.d_model, cfg.expert_width
+    e, held = cfg.moe.num_experts, cfg.moe.held
     ks = jax.random.split(key, 4)
     s = 1.0 / math.sqrt(d)
     return {
         "router": (jax.random.normal(ks[0], (d, e)) * s).astype(jnp.float32),
-        "w_gate": (jax.random.normal(ks[1], (e, d, f)) * s).astype(dtype),
-        "w_up": (jax.random.normal(ks[2], (e, d, f)) * s).astype(dtype),
-        "w_down": (jax.random.normal(ks[3], (e, f, d)) * s / math.sqrt(2 * cfg.n_layers)).astype(dtype),
+        "w_gate": (jax.random.normal(ks[1], (held, d, f)) * s).astype(dtype),
+        "w_up": (jax.random.normal(ks[2], (held, d, f)) * s).astype(dtype),
+        "w_down": (jax.random.normal(ks[3], (held, f, d)) * s / math.sqrt(2 * cfg.n_layers)).astype(dtype),
     }
+
+
+def route_held(router, cfg: ModelConfig, x):
+    """Routing of tokens ``x`` (T, d) over all ``num_experts``: a float32
+    softmax router, top-k, the k gates renormalised to sum 1.
+    Returns ``(gates (T, held) float32, held count (T,) int32)``: each
+    token's gate on every held expert (0 where it is not routed there) and
+    the number of its k choices that fall on a held expert."""
+    mcfg = cfg.moe
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    vals, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), mcfg.top_k)
+    vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    local = idx - mcfg.expert_offset                              # (T, k)
+    hit = local[..., None] == jnp.arange(mcfg.held)               # (T, k, H)
+    gates = jnp.sum(jnp.where(hit, vals[..., None], 0.0), axis=1)
+    return gates, jnp.sum(hit, axis=(1, 2), dtype=jnp.int32)
+
+
+def moe_held(params, experts, layer, cfg: ModelConfig, x, valid_len=None):
+    """Dropless MoE over the experts held on this chip (``cfg.moe.dropless``).
+
+    Every position is routed over all ``num_experts``
+    (:func:`route_held`); the assignments that fall on the held experts
+    (ids ``[expert_offset, expert_offset + held)``) run through the grouped
+    expert kernel with no capacity, and the gate-weighted sum of their
+    outputs is returned. What the experts held elsewhere would add is left
+    out. A position's output depends on its own hidden state alone.
+
+    ``params``: this layer's ``router``; ``experts``: the ``w_gate`` /
+    ``w_up`` / ``w_down`` stacks of every layer, ``layer`` the index into
+    them. Returns ``(out (B, S, d), held assignments (B,))``, counted at
+    positions below ``valid_len`` (every position when it is None)."""
+    from ..kernels.moe_experts import moe_experts
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    gates, hits = route_held(params["router"], cfg, xt)
+    out = moe_experts(xt, gates, experts["w_gate"], experts["w_up"],
+                      experts["w_down"], layer,
+                      per_token=min(cfg.moe.top_k, cfg.moe.held))
+    hits = hits.reshape(b, s)
+    if valid_len is not None:
+        hits = jnp.where(jnp.arange(s)[None, :] < valid_len[:, None], hits, 0)
+    return (out.astype(x.dtype).reshape(b, s, d),
+            jnp.sum(hits, axis=1, dtype=jnp.int32))
 
 
 def moe(params, cfg: ModelConfig, x, *, expert_parallel: bool = False):

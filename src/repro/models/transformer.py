@@ -4,7 +4,9 @@ Layers are grouped into repeating BLOCKS and parameters are stacked with a
 leading ``n_blocks`` dim; the forward pass is a single ``lax.scan`` over
 blocks. This keeps HLO size O(block) instead of O(n_layers) -- essential for
 compiling 64-72 layer configs for 512 devices -- and gives natural remat
-boundaries.
+boundaries. The held experts of a dropless MoE stay out of the scan: the
+expert kernel takes every layer's stack whole and indexes it by the layer
+(a slice taken in the scan would be copied before the kernel call).
 
 Block layouts:
   dense / moe / ssm : block = 1 layer
@@ -172,9 +174,23 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype=None,
 
 
 # ---------------------------------------------------------------- forward
+def _split_experts(cfg: ModelConfig, blocks):
+    """(blocks without the held-expert stacks, {slot: stacks}) for a
+    dropless MoE; (blocks, None) otherwise."""
+    if cfg.moe is None or not cfg.moe.dropless:
+        return blocks, None
+    rest, experts = dict(blocks), {}
+    for slot, sp in blocks.items():
+        if "moe" in sp:
+            moe = sp["moe"]
+            experts[slot] = {k: v for k, v in moe.items() if k != "router"}
+            rest[slot] = dict(sp, moe={"router": moe["router"]})
+    return rest, experts
+
+
 def _apply_block(cfg: ModelConfig, bp, h, positions, *, causal, cache_b,
                  cache_index, enc_out, collect_kv=False, use_pallas=False,
-                 valid_len=None):
+                 valid_len=None, experts=None, layer=None):
     aux = {}
     new_cache_b = {} if (cache_b is not None or collect_kv) else None
     for slot in range(block_size(cfg)):
@@ -211,6 +227,11 @@ def _apply_block(cfg: ModelConfig, bp, h, positions, *, causal, cache_b,
             hn = L.rms_norm(h, sp["norm2"], cfg.norm_eps)
             if mlpk == "dense":
                 h = h + L.mlp(sp["mlp"], cfg, hn)
+            elif experts is not None:
+                out, held = L.moe_held(sp["moe"], experts[f"slot{slot}"],
+                                       layer, cfg, hn, valid_len)
+                h = h + out
+                aux["moe_held"] = aux.get("moe_held", 0) + held
             else:
                 out, moe_aux = L.moe(sp["moe"], cfg, hn)
                 h = h + out
@@ -247,7 +268,9 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None, prefix=None,
 
     valid_len: optional (B,) int per-row true length for bucket-padded
     batches; threaded to attention so padded tail keys are masked out."""
-    """Returns dict(logits | eps, cache, aux).
+    """Returns dict(logits | eps, cache, aux), and ``moe_held`` (B,) for a
+    dropless MoE: the token-expert pairs its held experts computed per row
+    (at positions below ``valid_len``), summed over the layers.
 
     tokens: (B,S) int32; embeds: (B,S,D) continuous input (diffusion mode);
     prefix: (B,P,D) VLM patch embeddings; frames: (B,F,D) audio embeddings.
@@ -285,10 +308,14 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None, prefix=None,
             enc_out = _run_encoder(params, cfg, frames, unroll=unroll)
 
     collect_kv = (mode == "prefill")
+    blocks, experts = _split_experts(cfg, params["blocks"])
+    # the layer index rides in the scan only where the held experts need it
+    layers = None if experts is None \
+        else jnp.arange(n_blocks(cfg), dtype=jnp.int32)
 
     def body_inner(carry, xs):
         h = carry
-        bp, cache_b, cross_b = xs
+        bp, cache_b, cross_b, layer = xs
         if block_constraint is not None:
             bp = jax.tree.map(
                 lambda w, c: w if c is None else
@@ -299,7 +326,8 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None, prefix=None,
         h, new_cache_b, aux = _apply_block(
             cfg, bp, h, positions, causal=causal, cache_b=cache_b,
             cache_index=cache_index, enc_out=eo, collect_kv=collect_kv,
-            use_pallas=use_pallas, valid_len=valid_len)
+            use_pallas=use_pallas, valid_len=valid_len, experts=experts,
+            layer=layer)
         return h, (new_cache_b, aux)
 
     body = jax.checkpoint(body_inner) if remat else body_inner
@@ -309,8 +337,8 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None, prefix=None,
     unroll_n = n_blocks(cfg) if (unroll is True or unroll == 0) else int(unroll)
     if cache_blocks is None:
         h, (new_blocks, aux_stack) = jax.lax.scan(
-            lambda c, bp: body(c, (bp, None, None)), h, params["blocks"],
-            unroll=unroll_n)
+            lambda c, x: body(c, (x[0], None, None, x[1])), h,
+            (blocks, layers), unroll=unroll_n)
         new_cache = None
         if collect_kv:
             new_cache = {"blocks": new_blocks}
@@ -326,21 +354,26 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None, prefix=None,
                 new_cache["cross"] = jax.lax.map(cross_kv, params["blocks"])
     elif cross_blocks is None:
         h, (new_blocks, aux_stack) = jax.lax.scan(
-            lambda c, x: body(c, (x[0], x[1], None)), h,
-            (params["blocks"], cache_blocks), unroll=unroll_n)
+            lambda c, x: body(c, (x[0], x[1], None, x[2])), h,
+            (blocks, cache_blocks, layers), unroll=unroll_n)
         new_cache = dict(cache)
         new_cache["blocks"] = new_blocks
     else:
         h, (new_blocks, aux_stack) = jax.lax.scan(
-            body, h, (params["blocks"], cache_blocks, cross_blocks),
+            body, h, (blocks, cache_blocks, cross_blocks, layers),
             unroll=unroll_n)
         new_cache = dict(cache)
         new_cache["blocks"] = new_blocks
 
-    aux = {k: jnp.sum(v) for k, v in aux_stack.items()} if aux_stack else {}
+    aux_stack = dict(aux_stack or {})
+    held = aux_stack.pop("moe_held", None)
+    aux = {k: jnp.sum(v) for k, v in aux_stack.items()}
 
     h = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
     out = {"cache": new_cache, "aux": aux, "hidden": h}
+    if held is not None:
+        # per row: assignments the held experts computed, over the layers
+        out["moe_held"] = jnp.sum(held, axis=0, dtype=jnp.int32)
     if cfg.objective == "diffusion" and embeds is not None:
         out["eps"] = L.matmul(h, params["eps_head"])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

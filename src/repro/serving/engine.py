@@ -199,6 +199,12 @@ class Result:
     final_err: float | None = None  # last local-error estimate of the row
                                     # (None when its plan carries no
                                     # embedded pair or no estimate exists)
+    moe_assignments: int | None = None  # token-expert pairs the held
+                                    # experts of a dropless MoE computed for
+                                    # this request: its valid positions,
+                                    # every layer, every NFE (None: no such
+                                    # experts, or a pndm plan, whose steps
+                                    # run their evals under a cond)
 
 
 @dataclasses.dataclass
@@ -342,6 +348,7 @@ class _Row:
     k0: int = 0                 # group step count at this row's admission
     solve_s0: float = 0.0       # group solve_s at this row's admission
     wait_s: float = 0.0         # submit -> admission queue wait
+    moe: int = 0                # held-expert assignments computed so far
 
 
 @dataclasses.dataclass
@@ -422,8 +429,10 @@ class DiffusionServeEngine:
         bucket) and a per-row ``lens`` vector masks padded tail keys out
         of every attention call, so the valid positions never see the
         tail. (Stochastic per-step solve noise is still drawn at bucket
-        shape, and MoE capacity is still shared with tail tokens -- those
-        rows keep a bucket-shape dependence.)
+        shape, so those rows keep a bucket-shape dependence. A dropless
+        MoE routes each position on its own hidden state, so tail
+        positions never touch valid rows; the capacity-dispatch MoE of
+        the training substrate shares its capacity with them.)
 
         ``mesh``: a ``jax.sharding.Mesh`` with a data-like axis (e.g.
         :func:`repro.launch.mesh.make_request_mesh`) shards every stacked
@@ -590,6 +599,10 @@ class DiffusionServeEngine:
         self._m_saved_nfe = reg.counter(
             "serve_saved_nfe_total",
             "network evals saved by early exit (budgeted minus spent)")
+        self._m_moe = reg.counter(
+            "serve_moe_assignments_total",
+            "token-expert pairs the held experts of a dropless MoE computed "
+            "at valid positions, over every layer and NFE")
         self._h_queue_wait = reg.histogram(
             "serve_queue_wait_seconds", "submit -> admission (join or fresh)")
         self._h_row_err = reg.histogram(
@@ -687,13 +700,17 @@ class DiffusionServeEngine:
             return self._compiled[key_], 0.0
         self._m_cache_misses.inc()
         cfg, mesh = self.cfg, self.mesh
+        counting = self._counts_moe(plan)
 
         def run(params, plan_arg, k, st, lens):
-            return SAMPLER.step(plan_arg, k, st,
-                                DLM.make_tiled_eps_fn(params, cfg,
-                                                      valid_len=lens,
-                                                      mesh=mesh),
-                                mesh=mesh)
+            taps = [] if counting else None
+            new = SAMPLER.step(plan_arg, k, st,
+                               DLM.make_tiled_eps_fn(params, cfg,
+                                                     valid_len=lens,
+                                                     mesh=mesh,
+                                                     held_counts=taps),
+                               mesh=mesh)
+            return (new, sum(taps)) if counting else new
 
         # k is lowered as a PER-ROW (R,) step vector: one trace serves both
         # groups admitted whole (all entries equal -- bitwise identical to a
@@ -715,7 +732,8 @@ class DiffusionServeEngine:
                                   self.mesh)
             jitted = jax.jit(run, in_shardings=(param_sh, plan_sh, row_sh,
                                                 state_sh, row_sh),
-                             out_shardings=state_sh)
+                             out_shardings=(state_sh, row_sh) if counting
+                             else state_sh)
         with self.tracer.span("compile"):
             lowered = jitted.lower(self._params_exec, plan, rows, state, rows)
             # the row moves ending at this batch are lowered while the step
@@ -733,6 +751,13 @@ class DiffusionServeEngine:
         self._m_compile_s.inc(compile_s)
         self._compiled[key_] = compiled
         return compiled, compile_s
+
+    def _counts_moe(self, plan: SolverPlan) -> bool:
+        """Whether the step returns, beside the state, the per-row count of
+        assignments the held experts of a dropless MoE computed. pndm runs
+        its evals under a ``lax.cond``, out of the count's reach."""
+        return self.cfg.moe is not None and self.cfg.moe.dropless \
+            and plan.method != "pndm"
 
     def _row_moves(self, plan: SolverPlan, state) -> list:
         """The row moves that end at this batch of ``R`` rows, lowered: the
@@ -956,7 +981,9 @@ class DiffusionServeEngine:
                 self._boundary_results.append(Result(
                     r.req.uid, toks[j][:r.req.seq_len], lat, nfe=spent,
                     compile_s=g.compile_s, queue_wait_s=r.wait_s,
-                    early_exit=True, final_err=float(err[i])))
+                    early_exit=True, final_err=float(err[i]),
+                    moe_assignments=r.moe if self._counts_moe(g.plan)
+                    else None))
             if not any(not r.done for r in g.rows):
                 self._active.remove(g)
 
@@ -1324,10 +1351,12 @@ class DiffusionServeEngine:
                     [r.req.seq_len if r.req is not None else g.seq_len
                      for r in g.rows], jnp.int32)
                 t0 = time.perf_counter()
-                g.state = g.fn(self._params_exec, g.plan, k_vec, g.state,
-                               lens_vec)
-                dispatched.append((g, t0))
-        for g, t0 in dispatched:
+                out = g.fn(self._params_exec, g.plan, k_vec, g.state,
+                           lens_vec)
+                g.state, held = out if self._counts_moe(g.plan) \
+                    else (out, None)
+                dispatched.append((g, t0, held))
+        for g, t0, held in dispatched:
             # rows: live request rows; slots: rows the executor steps
             with self.tracer.span("step_wait",
                                   rows=sum(not r.done for r in g.rows),
@@ -1335,6 +1364,14 @@ class DiffusionServeEngine:
                 # repro: allow[RL001] THE documented boundary sync: one wait per
                 # group-step after all groups dispatched (see module docstring)
                 jax.block_until_ready(g.state.x)
+                if held is not None:
+                    # repro: allow[RL001] R ints of the step just waited on
+                    held = np.asarray(held)
+                    live = [i for i, r in enumerate(g.rows)
+                            if not (r.done or r.pad)]
+                    for i in live:
+                        g.rows[i].moe += int(held[i])
+                    self._m_moe.inc(int(sum(held[i] for i in live)))
             dt_step = time.perf_counter() - t0
             g.solve_s += dt_step
             self._h_step.observe(dt_step)
@@ -1397,7 +1434,9 @@ class DiffusionServeEngine:
                             row.req.uid, new_toks[j][:row.req.seq_len],
                             g.solve_s - row.solve_s0, nfe=row.nfe,
                             compile_s=g.compile_s, queue_wait_s=row.wait_s,
-                            final_err=f_err)
+                            final_err=f_err,
+                            moe_assignments=row.moe if held is not None
+                            else None)
                         self._m_completed.inc()
                         self._h_queue_wait.observe(res.queue_wait_s)
                         self._h_solve.observe(res.latency_s)

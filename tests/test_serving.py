@@ -667,6 +667,53 @@ def test_seq_len_bucket_content_matches_unbucketed(diff_setup):
     np.testing.assert_array_equal(got.tokens, want.tokens)
 
 
+# ------------------------------------------------ a dropless MoE eps-net
+@pytest.fixture(scope="module")
+def moe_setup():
+    """sdar-30b-a3b at a tiny size as one chip's share: 4 of 8 experts held
+    (ids 2-5), top-2, dropless routing through the expert kernel."""
+    cfg = get_config("sdar_30b_a3b").reduced().with_(objective="diffusion")
+    cfg = cfg.with_(moe=dataclasses.replace(
+        cfg.moe, num_experts=8, top_k=2, experts_held=4, expert_offset=2))
+    return T.init_params(cfg, jax.random.PRNGKey(0)), cfg
+
+
+def test_join_at_compaction_boundary_bitwise_vs_solo_moe(moe_setup):
+    """The join case on a dropless MoE: the joiner is spliced in, and every
+    row decodes bitwise as it does served alone."""
+    test_join_at_compaction_boundary_bitwise_vs_solo(moe_setup)
+
+
+def test_seq_len_bucket_content_matches_unbucketed_moe(moe_setup):
+    """Bucket-independence on a dropless MoE: the tail positions share no
+    capacity with the valid ones."""
+    test_seq_len_bucket_content_matches_unbucketed(moe_setup)
+
+
+def test_moe_assignments_counted_per_request(moe_setup, diff_setup):
+    """``Result.moe_assignments``: the held experts' token-expert pairs at
+    the request's own positions over every layer and NFE, the same stacked
+    as solo, and summed in ``serve_moe_assignments_total``; a pndm plan,
+    whose evals run under a cond, reports None."""
+    params, cfg = moe_setup
+    reqs = [Request(uid=i, seq_len=n, nfe=4, solver="tab3", seed=i)
+            for i, n in enumerate([16, 11, 6])]
+    eng = DiffusionServeEngine(params, cfg, seq_len_buckets=(16,))
+    got = {r.uid: r for r in eng.serve(reqs)}
+    solo = DiffusionServeEngine(params, cfg, seq_len_buckets=(16,))
+    for q in reqs:
+        n = got[q.uid].moe_assignments
+        assert n == solo.serve([dataclasses.replace(q)])[0].moe_assignments
+        assert 0 < n < q.seq_len * cfg.n_layers * got[q.uid].nfe * 2
+    assert eng.metrics.get("serve_moe_assignments_total").value \
+        == sum(r.moe_assignments for r in got.values())
+    pndm = eng.serve([Request(uid=9, seq_len=16, nfe=13, solver="pndm")])
+    assert pndm[0].moe_assignments is None
+    dense, dcfg = diff_setup
+    assert DiffusionServeEngine(dense, dcfg).serve(
+        [Request(uid=0, seq_len=8, nfe=3)])[0].moe_assignments is None
+
+
 def test_seq_len_bucket_stream_decode_masks_tail(diff_setup):
     """stream_decode under bucketing: group events carry bucket-length rows
     plus row_seq_lens so consumers (the driver) can mask the tail; final

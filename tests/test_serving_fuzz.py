@@ -135,6 +135,27 @@ def test_fuzz_traffic_bitwise_vs_solo_and_warm_cache(diff_setup, solo_engine,
         np.testing.assert_array_equal(warm[uid].tokens, got[uid].tokens)
 
 
+@pytest.fixture(scope="module")
+def moe_setup():
+    """sdar-30b-a3b at a tiny size as one chip's share: 4 of 8 experts held
+    (ids 2-5), top-2, dropless routing through the expert kernel."""
+    import dataclasses
+    cfg = get_config("sdar_30b_a3b").reduced().with_(objective="diffusion")
+    cfg = cfg.with_(moe=dataclasses.replace(
+        cfg.moe, num_experts=8, top_k=2, experts_held=4, expert_offset=2))
+    return T.init_params(cfg, jax.random.PRNGKey(0)), cfg
+
+
+def test_fuzz_traffic_moe_bitwise_vs_solo_and_warm_cache(moe_setup):
+    """The fuzz case above on a dropless MoE eps-net (joins on): grouping,
+    joins and compaction never change what a request computes, and the
+    warm replay compiles nothing."""
+    params, cfg = moe_setup
+    solo = DiffusionServeEngine(params, cfg, seq_len_buckets=(8,))
+    test_fuzz_traffic_bitwise_vs_solo_and_warm_cache(moe_setup, solo,
+                                                     join=True, fuzz_seed=0)
+
+
 def test_fuzz_joins_admit_into_inflight_groups(diff_setup):
     """Sanity on the fuzz harness itself: with joins on, a continuous
     ragged stream (a short+long pair arriving every tick, so retired rows
