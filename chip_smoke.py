@@ -13,6 +13,9 @@ The default run builds h2o-danube-3-4b at its published widths in bf16
   an RK plan (rho_heun: the unfused path);
 * the same tab3 request again in a stacked group of four, which must give
   the solo result bit for bit (the reproducibility invariant);
+* the same invariant at seq 32 and 64, whose eps tiles hold 8 and 4 rows:
+  the served eps of each row of every group of 1 to 8 rows against the row
+  alone, bitwise, and a tab3 request solo against a stacked group of 8;
 * one engine with a ``RetirePolicy``, so the kernel's error-pair output
   runs.
 
@@ -40,8 +43,10 @@ import traceback
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
 
 import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.diffusion import lm as DLM  # noqa: E402
 from repro.launch import serve  # noqa: E402
 from repro.serving.driver import ServeDriver  # noqa: E402
 from repro.serving.engine import Request  # noqa: E402
@@ -51,6 +56,8 @@ SEQ_LEN = 256
 NFE = 10
 SERVED = ("tab3", "sndeis2", "seeds2", "rho_heun")
 STACK = 4                 # rows of the stacked tab3 group
+SHORT_SEQ_LENS = (32, 64)  # buckets whose eps tiles are wider than 2 rows
+GROUP_MAX = 8              # the engine's default max_group
 RESULT_TIMEOUT_S = 900.0
 
 
@@ -74,9 +81,10 @@ def _serve_args(seed: int, reduced: bool, *extra: str):
                                                   else []))
 
 
-def _tokens_ok(check: Checks, name: str, res, vocab: int) -> bool:
+def _tokens_ok(check: Checks, name: str, res, vocab: int,
+               seq_len: int = SEQ_LEN) -> bool:
     toks = np.asarray(res.tokens)
-    ok = (toks.shape == (SEQ_LEN,) and np.issubdtype(toks.dtype, np.integer)
+    ok = (toks.shape == (seq_len,) and np.issubdtype(toks.dtype, np.integer)
           and bool(np.all((toks >= 0) & (toks < vocab))))
     return check(f"tokens[{name}]", ok,
                  f"shape={toks.shape} range=[{toks.min(initial=0)}, "
@@ -158,6 +166,9 @@ def default_phase(check: Checks, seed: int, reduced: bool) -> None:
           and np.array_equal(solo[0].tokens, by_uid[100].tokens),
           f"tab3 executor batches={batches}")
 
+    for s_len in SHORT_SEQ_LENS:
+        short_tiles(check, eng, cfg, params, s_len, vocab)
+
     rargs = _serve_args(seed, reduced, "--early-exit-tol", "1e-3")
     eng_r = serve.build_diffusion_engine(rargs, cfg, params)
     _drive(check, eng_r, [Request(uid=200, seq_len=SEQ_LEN, nfe=NFE,
@@ -165,6 +176,44 @@ def default_phase(check: Checks, seed: int, reduced: bool) -> None:
     _kernels_compiled(check, "default", eng)
     _kernels_compiled(check, "retire", eng_r)
     print(f"compile_seconds_total {_compile_seconds(eng, eng_r):.3f}")
+
+
+def short_tiles(check: Checks, eng, cfg, params, s_len: int,
+                vocab: int) -> None:
+    """Solo == stacked at a bucket whose eps tile is wider than 2 rows:
+    the served eps of every group of 1 to GROUP_MAX rows, row by row
+    against each row alone, and a tab3 request solo against the first row
+    of a stacked group of GROUP_MAX."""
+    tile = DLM.tile_rows(s_len)
+    eps = jax.jit(lambda p, x, t, vl: DLM.make_tiled_eps_fn(
+        p, cfg, valid_len=vl)(x, t))
+    x = jax.random.normal(jax.random.PRNGKey(s_len),
+                          (GROUP_MAX, s_len, cfg.d_model), jnp.float32)
+    t = jnp.linspace(0.3, 0.9, GROUP_MAX, dtype=jnp.float32)
+    lens = jnp.asarray([s_len - 3 * i for i in range(GROUP_MAX)], jnp.int32)
+    solo = [np.asarray(eps(params, x[i:i + 1], t[i:i + 1], lens[i:i + 1]))[0]
+            for i in range(GROUP_MAX)]
+    bad = [(r, i) for r in range(1, GROUP_MAX + 1)
+           for i, row in enumerate(np.asarray(eps(params, x[:r], t[:r],
+                                                  lens[:r])))
+           if not np.array_equal(row, solo[i])]
+    check(f"tiled_eps_solo_vs_stacked[seq={s_len} tile={tile}]", not bad,
+          f"groups 1..{GROUP_MAX}, mismatched (rows, row)={bad[:8]}")
+
+    def req(uid, seed):
+        return Request(uid=uid, seq_len=s_len, nfe=NFE, solver="tab3",
+                       seed=seed)
+    one = eng.serve([req(300 + s_len, 0)])[0]
+    group = {r.uid: r for r in eng.serve(
+        [req(400 + s_len + j, j) for j in range(GROUP_MAX)])}
+    first = group.get(400 + s_len)
+    ok = (first is not None
+          and _tokens_ok(check, f"tab3 seq={s_len} solo", one, vocab, s_len)
+          and _tokens_ok(check, f"tab3 seq={s_len} stacked", first, vocab,
+                         s_len)
+          and np.array_equal(one.tokens, first.tokens))
+    check(f"solo_vs_stacked_bitwise[seq={s_len} tile={tile}]", ok,
+          f"stack={len(group)}")
 
 
 def four_chips_phase(check: Checks, seed: int, reduced: bool) -> None:

@@ -542,6 +542,19 @@ def step(plan: SolverPlan, k, state: SamplerState, eps_fn: EpsFn, *,
                                        hooks or _DEFAULT_HOOKS)
 
 
+def step_evals(plan: SolverPlan, ks) -> int:
+    """Calls of ``eps_fn`` one :func:`step` of the stacked ``plan`` runs at
+    the host per-row step vector ``ks``: one for ab, the stage count for
+    rk; pndm runs its 4-eval warmup branch, its 1-eval tail, or both when
+    its rows sit on either side of the split."""
+    if plan.method == "rk":
+        return plan.coeffs["b"].shape[-1]
+    if plan.method == "pndm":
+        warm = [min(k, plan.n_steps - 1) < _N_WARMUP for k in ks]
+        return 4 * any(warm) + (not all(warm))
+    return 1
+
+
 def sample(plan: SolverPlan, eps_fn: EpsFn, x_T: Array,
            key: Optional[Array] = None, *, hooks: Optional[Hooks] = None,
            mesh=None):

@@ -100,17 +100,30 @@ def make_eps_fn(params, cfg: ModelConfig, *, prefix=None, frames=None,
     return eps_fn
 
 
-# Rows per eps forward in the serving executors. On a v5e at seq 32 (the
-# weight read bounds a forward) a 2-row tile costs what one row does; at
-# seq 256 it costs 1.4x one row and 0.8x two rows run one at a time.
+# Rows per eps tile in the serving executors, from the group's padded
+# length S. A forward is bound by the read of the weights until a tile holds
+# about TILE_TOKENS tokens: a v5e's 197 TFLOP/s over 819 GB/s is 240 FLOP
+# per byte, so about 256 tokens (the MXU's 128 multiple) per bf16 weight
+# read. Short buckets take as many rows as fill that (seq 32: 8 rows, seq
+# 64: 4), never more than MAX_TILE_ROWS, the engine's default max_group (a
+# wider tile would only add pad rows); seq 128 and longer keep ROW_TILE.
+TILE_TOKENS = 256
 ROW_TILE = 2
+MAX_TILE_ROWS = 8
+
+
+def tile_rows(seq_len: int) -> int:
+    """Rows per eps tile for a group of padded length ``seq_len``. It reads
+    the bucket, which every row of a group shares, and never the group's
+    row count: a row rounds alike in every group of its bucket."""
+    return min(MAX_TILE_ROWS, max(ROW_TILE, TILE_TOKENS // seq_len))
 
 
 def make_tiled_eps_fn(params, cfg: ModelConfig, *, valid_len, mesh=None,
                       held_counts: list | None = None):
-    """:func:`make_eps_fn` run one tile of :data:`ROW_TILE` rows at a time:
-    the serving executors' eps, for ``(R, S, D)`` groups with a per-row
-    ``(R,)`` ``valid_len``.
+    """:func:`make_eps_fn` run one tile of :func:`tile_rows` rows at a
+    time: the serving executors' eps, for ``(R, S, D)`` groups with a
+    per-row ``(R,)`` ``valid_len``.
 
     ``held_counts``: a list to which each call appends the ``(R,)``
     token-expert pairs a dropless MoE's held experts computed per row, at
@@ -121,9 +134,10 @@ def make_tiled_eps_fn(params, cfg: ModelConfig, *, valid_len, mesh=None,
     TPU does not give that for a batched forward: it compiles a different
     program for each row count, and on a v5e the rows of 1-, 2-, 3-, 4-
     and 8-row forwards all round differently. A loop over fixed tiles runs
-    every row through the same ``ROW_TILE``-row program whatever the
-    group, and a row's bits do not depend on its place in the tile. Under
-    a request-axis ``mesh`` each device loops over its own rows
+    every row through the same tile program whatever the group, and a
+    row's bits do not depend on its place in the tile. The tile's width is
+    a function of ``S`` alone, so every group of a bucket runs one body.
+    Under a request-axis ``mesh`` each device loops over its own rows
     (``shard_map``), so no row is computed twice."""
     counting = held_counts is not None and cfg.moe is not None \
         and cfg.moe.dropless
@@ -135,21 +149,20 @@ def make_tiled_eps_fn(params, cfg: ModelConfig, *, valid_len, mesh=None,
         # (it would inline the body) nor a slice that is the whole operand
         # (it would drop the slice and hoist the tile's work out of the
         # loop): every group size runs the same loop body.
-        r = x.shape[0]
-        spare = -(-r // ROW_TILE) * ROW_TILE + ROW_TILE - r
+        r, tile = x.shape[0], tile_rows(x.shape[1])
+        spare = -(-r // tile) * tile + tile - r
         xp, tp, vp = (jnp.concatenate([a, jnp.repeat(a[:1], spare, 0)])
                       for a in (x, t, vl))
-        n = (jnp.sum(vl >= 0, dtype=jnp.int32) + ROW_TILE - 1) // ROW_TILE
+        n = (jnp.sum(vl >= 0, dtype=jnp.int32) + tile - 1) // tile
 
         def one(i, carry):
-            def tile(a):
-                return jax.lax.dynamic_slice_in_dim(a, i * ROW_TILE, ROW_TILE)
+            def take(a):
+                return jax.lax.dynamic_slice_in_dim(a, i * tile, tile)
 
             def put(a, v):
-                return jax.lax.dynamic_update_slice_in_dim(a, v, i * ROW_TILE,
-                                                           0)
-            vl_i = tile(vp)
-            e, h = _eps_forward(params, cfg, tile(xp), tile(tp),
+                return jax.lax.dynamic_update_slice_in_dim(a, v, i * tile, 0)
+            vl_i = take(vp)
+            e, h = _eps_forward(params, cfg, take(xp), take(tp),
                                 valid_len=vl_i)
             if counting:
                 return put(carry[0], e), put(carry[1], h)

@@ -25,7 +25,11 @@ Admission is *continuous*: at every step boundary pending requests
 (``max_group`` minus its live rows; retired rows and structural filler are
 slots too) instead of waiting for a fresh one -- so a request that arrives
 while another of its bucket is in flight shares that group's row tiles
-rather than running a half-empty tile of its own. Joiner plan rows are
+rather than running a half-empty tile of its own (a tile is
+:func:`repro.diffusion.lm.tile_rows` of the bucket's length wide: 8 rows at
+seq 32, 2 at seq 128; ``serve_eps_tiles_total`` and
+``serve_eps_tile_rows_total`` count the tiles run and their row slots).
+Joiner plan rows are
 padded to the group's grid and spliced onto the in-flight rows, which stay
 bitwise unmoved, and the executor steps every row at its OWN count (a
 per-row ``k`` vector: joiners start at 0 while veterans continue), so a
@@ -599,6 +603,13 @@ class DiffusionServeEngine:
         self._m_saved_nfe = reg.counter(
             "serve_saved_nfe_total",
             "network evals saved by early exit (budgeted minus spent)")
+        self._m_tiles = reg.counter(
+            "serve_eps_tiles_total",
+            "eps tile forwards run: tile-loop trips times eps calls of "
+            "each group step, summed over devices")
+        self._m_tile_rows = reg.counter(
+            "serve_eps_tile_rows_total",
+            "row slots of the eps tiles run (tiles times the tile's rows)")
         self._m_moe = reg.counter(
             "serve_moe_assignments_total",
             "token-expert pairs the held experts of a dropless MoE computed "
@@ -751,6 +762,18 @@ class DiffusionServeEngine:
         self._m_compile_s.inc(compile_s)
         self._compiled[key_] = compiled
         return compiled, compile_s
+
+    def _count_tiles(self, g: _Group, ks: list) -> None:
+        """Count the eps tiles one step of ``g`` at the per-row step
+        vector ``ks`` runs: each device loops over its own rows in tiles
+        of the bucket's :func:`~repro.diffusion.lm.tile_rows`, once per
+        eps call of the step."""
+        tile = DLM.tile_rows(g.seq_len)
+        per_device = len(g.rows) // self._data_size
+        tiles = self._data_size * -(-per_device // tile) \
+            * SAMPLER.step_evals(g.plan, ks)
+        self._m_tiles.inc(tiles)
+        self._m_tile_rows.inc(tiles * tile)
 
     def _counts_moe(self, plan: SolverPlan) -> bool:
         """Whether the step returns, beside the state, the per-row count of
@@ -1118,7 +1141,8 @@ class DiffusionServeEngine:
         recompile) or whose priority differs from the group's live rows'
         (a joiner would lift or sink the group under ``steps_per_tick``).
         Where the group or a joiner carries a deadline, joiners only fill
-        the free slots of the live rows' last row tile: one tile more would
+        the free slots of the live rows' last row tile (the bucket's
+        :func:`~repro.diffusion.lm.tile_rows` wide): one tile more would
         slow every step of the deadline row. Empty when nothing can join."""
         live = [r for r in g.rows if not r.done]
         cap = self._chunk_cap - len(live)
@@ -1126,7 +1150,7 @@ class DiffusionServeEngine:
             return []
         prio = max(r.req.priority for r in live)
         deadline = min(r.deadline for r in live)
-        room = -len(live) % DLM.ROW_TILE
+        room = -len(live) % DLM.tile_rows(g.seq_len)
         take, rest = [], []
         for p in cands:
             due = self._abs_deadline(p.req, p.t_sub)
@@ -1346,7 +1370,9 @@ class DiffusionServeEngine:
                 # stays zero.
                 self._m_wasted.inc(sum(
                     r.done and not r.pad for r in g.rows))
-                k_vec = jnp.asarray([g.k - r.k0 for r in g.rows], jnp.int32)
+                ks = [g.k - r.k0 for r in g.rows]
+                self._count_tiles(g, ks)
+                k_vec = jnp.asarray(ks, jnp.int32)
                 lens_vec = jnp.asarray(
                     [r.req.seq_len if r.req is not None else g.seq_len
                      for r in g.rows], jnp.int32)

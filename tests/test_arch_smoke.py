@@ -167,33 +167,63 @@ def test_pallas_kernel_routing_matches_xla(arch):
                                np.asarray(b, np.float32), rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("seq_len,rows", [(8, 8), (16, 8), (32, 8), (64, 4),
+                                          (128, 2), (256, 2)])
+def test_tile_rows_fill_the_ridge_from_the_bucket(seq_len, rows):
+    """An eps tile holds about 256 tokens, 2 to 8 rows, chosen from the
+    bucket's length; the lowered eps loop slices that many rows per trip
+    whatever the group's size (seq 128 and 256 keep the 2-row body)."""
+    import re
+    from repro.diffusion import lm as DLM
+    assert DLM.tile_rows(seq_len) == rows
+    cfg = get_config("h2o_danube_3_4b").reduced().with_(
+        objective="diffusion", n_layers=1)
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    d = cfg.d_model
+    for r in (1, 3, 8):
+        text = jax.jit(lambda p, x, t, vl: DLM.make_tiled_eps_fn(
+            p, cfg, valid_len=vl)(x, t)).lower(
+                params, jax.ShapeDtypeStruct((r, seq_len, d), jnp.float32),
+                jax.ShapeDtypeStruct((r,), jnp.float32),
+                jax.ShapeDtypeStruct((r,), jnp.int32)).as_text()
+        padded = -(-r // rows) * rows + rows     # whole tiles + a spare
+        sliced = re.findall(
+            rf"dynamic_slice .*\(tensor<{padded}x{seq_len}x{d}xf32>.*"
+            rf"-> tensor<(\d+)x{seq_len}x{d}xf32>", text)
+        assert sliced == [str(rows)]
+
+
+@pytest.mark.parametrize("seq_len", [32, 64, 128])
 @pytest.mark.parametrize("width", [256, 3840])
-def test_diffusion_eps_row_is_batch_invariant_bf16(width):
+def test_diffusion_eps_row_is_batch_invariant_bf16(width, seq_len):
     """A row's served eps in bf16 does not depend on how many rows share
     the call -- the serving invariant (stacked row == solo row, bitwise) at
     the served dtype. The batched forward breaks it at d_model 3840 even on
     the CPU (its time MLP is a (B, D) product, which rounds differently at
     B = 1 than at B = 4); the serving executors' eps, a loop over fixed
-    tiles of rows, holds at every group size."""
+    tiles of rows, holds at every group size, at each bucket's tile width
+    (8, 4 and 2 rows at seq 32, 64 and 128)."""
     from repro.diffusion import lm as DLM
     cfg = get_config("h2o_danube_3_4b").reduced().with_(
         objective="diffusion", dtype="bfloat16", n_layers=1, d_model=width)
     params = T.init_params(cfg, jax.random.PRNGKey(0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, width), jnp.float32)
-    t = jnp.linspace(0.3, 0.9, 4, dtype=jnp.float32)
-    lens = jnp.full((4,), 16, jnp.int32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (8, seq_len, width),
+                          jnp.float32)
+    t = jnp.linspace(0.3, 0.9, 8, dtype=jnp.float32)
+    lens = jnp.full((8,), seq_len, jnp.int32)
     eps = jax.jit(lambda p, x, t, vl: DLM.make_tiled_eps_fn(
         p, cfg, valid_len=vl)(x, t))
     full = np.asarray(eps(params, x, t, lens))
-    for i in range(4):
+    for i in range(8):
         np.testing.assert_array_equal(
             full[i], np.asarray(eps(params, x[i:i + 1], t[i:i + 1],
                                     lens[i:i + 1]))[0])
-    for r in (2, 3):   # a row's place in its tile, and a part-filled tile
+    # every group size: a row's place in its tile, part-filled tiles
+    for r in range(2, 8):
         np.testing.assert_array_equal(
             full[:r], np.asarray(eps(params, x[:r], t[:r], lens[:r])))
     # a one-tile group still runs the loop: XLA inlines a loop it can see
     # runs once, which would give small groups a program of their own
-    for r in (1, 4):
+    for r in sorted({1, DLM.tile_rows(seq_len)}):
         assert "while(" in eps.lower(params, x[:r], t[:r],
                                      lens[:r]).compile().as_text()

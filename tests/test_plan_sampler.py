@@ -348,6 +348,35 @@ def test_stacked_state_validation():
         init_state(plan, xT[:1], _per_request_keys([1, 2]))
 
 
+@pytest.mark.parametrize("name,ks,evals", [
+    ("tab3", [0, 5], 1),
+    ("rho_heun", [0, 5], 2),
+    ("rho_rk4", [2, 2], 4),
+    ("pndm", [0, 2], 4),        # every row in the warmup
+    ("pndm", [3, 7], 1),        # every row in the tail
+    ("pndm", [1, 4], 5),        # rows on both sides: both branches run
+])
+def test_step_evals_counts_the_eps_calls_a_step_runs(name, ks, evals):
+    """``step_evals`` is the number of eps calls one jitted stacked step
+    runs at the per-row step vector ``ks``, counted on the device side
+    (a call inside a ``lax.cond`` branch counts only where it runs)."""
+    from repro.core.sampler import step_evals
+    _, xT = _problem(batch=2)
+    ts = get_timesteps(SDE, 8, "uniform") if name == "pndm" else TS
+    plan = stack_plans([make_plan(name, SDE, ts)] * 2)
+    ran = []
+
+    def eps(x, t):
+        jax.debug.callback(lambda: ran.append(1))
+        return x * 0.1
+
+    out = jax.jit(lambda k, st: step(plan, k, st, eps))(
+        jnp.asarray(ks, jnp.int32), init_state(plan, xT))
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+    assert len(ran) == evals == step_evals(plan, ks)
+
+
 def test_plan_nfe_accounting():
     assert make_plan("pndm", SDE, get_timesteps(SDE, 20, "uniform")).nfe == 29
     assert make_plan("ipndm3", SDE, TS).nfe == 8

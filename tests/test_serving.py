@@ -528,34 +528,64 @@ def test_inflight_join_by_bucket_and_priority(diff_setup, uid, seq_len,
                                       got[q.uid].tokens)
 
 
-@pytest.mark.parametrize("n_first,first_due,late_due,joins", [
-    (1, 60.0, None, True),     # a lone deadline row: the joiner fills its tile
-    (2, 60.0, None, False),    # the tile is full: a third row would add one
-    (2, None, 60.0, False),    # nor may a deadline joiner open one
-    (2, None, None, True),     # best-effort rows: the joiner opens a tile
+@pytest.mark.parametrize("seq_len,n_first,first_due,late_due,n_late,joined", [
+    (128, 1, 60.0, None, 1, 1),  # a lone deadline row: the joiner fills its tile
+    (128, 2, 60.0, None, 1, 0),  # the tile is full: a third row would add one
+    (128, 2, None, 60.0, 1, 0),  # nor may a deadline joiner open one
+    (128, 2, None, None, 1, 1),  # best-effort rows: the joiner opens a tile
+    (32, 3, 60.0, None, 6, 5),   # an 8-row tile: 3 deadline rows take 5
+    (32, 8, 60.0, None, 1, 0),   # the 8-row tile is full
+    (32, 3, None, None, 6, 6),   # best-effort rows take all 6
 ], ids=["deadline_fills_tile", "deadline_full_tile", "deadline_joiner",
-        "best_effort"])
-def test_join_adds_no_tile_to_a_deadline_row(diff_setup, n_first, first_due,
-                                             late_due, joins):
+        "best_effort", "deadline_fills_tile8", "deadline_full_tile8",
+        "best_effort_tile8"])
+def test_join_adds_no_tile_to_a_deadline_row(diff_setup, seq_len, n_first,
+                                             first_due, late_due, n_late,
+                                             joined):
     """Under ``enforce_deadlines`` a join never makes a deadline row's steps
     run one row tile more: where the group or the joiner carries a
-    deadline, a joiner only fills a free slot of the live rows' last tile,
-    else it forms its own group. No deadline is missed either way."""
+    deadline, a joiner only fills a free slot of the live rows' last tile
+    (2 rows wide at seq 128, 8 at seq 32), else it forms its own group. No
+    deadline is missed either way."""
     params, cfg = diff_setup
-    eng = DiffusionServeEngine(params, cfg, enforce_deadlines=True)
+    eng = DiffusionServeEngine(params, cfg, enforce_deadlines=True,
+                               max_group=16)
     for j in range(n_first):
-        eng.submit(Request(uid=j, seq_len=16, nfe=4, solver="tab2", seed=j,
-                           deadline_s=first_due))
+        eng.submit(Request(uid=j, seq_len=seq_len, nfe=4, solver="tab2",
+                           seed=j, deadline_s=first_due))
     out = eng.tick()
-    eng.submit(Request(uid=9, seq_len=16, nfe=4, solver="tab2", seed=9,
-                       deadline_s=late_due))
+    late = list(range(100, 100 + n_late))
+    for uid in late:
+        eng.submit(Request(uid=uid, seq_len=seq_len, nfe=4, solver="tab2",
+                           seed=uid, deadline_s=late_due))
     out += eng.tick()
-    assert eng.joined_requests == int(joins)
-    assert len(eng._active) == (1 if joins else 2)
+    assert eng.joined_requests == joined
+    assert len(eng._active) == (1 if joined == n_late else 2)
     while eng.busy:
         out += eng.tick()
-    assert sorted(r.uid for r in out) == list(range(n_first)) + [9]
+    assert sorted(r.uid for r in out) == list(range(n_first)) + late
     assert not any(r.deadline_exceeded for r in out)
+
+
+@pytest.mark.parametrize("solver,seq_len,rows,tiles_per_step", [
+    ("tab2", 32, 3, 1),       # one 8-row tile holds the group
+    ("tab2", 128, 3, 2),      # 2-row tiles: one full, one part-filled
+    ("rho_heun", 32, 3, 2),   # two eps calls per step
+])
+def test_eps_tile_counters(diff_setup, solver, seq_len, rows, tiles_per_step):
+    """``serve_eps_tiles_total`` counts the tile forwards a group step runs
+    (the tile loop's trips times the step's eps calls) and
+    ``serve_eps_tile_rows_total`` the row slots they offer."""
+    params, cfg = diff_setup
+    eng = DiffusionServeEngine(params, cfg)
+    out = eng.serve([Request(uid=j, seq_len=seq_len, nfe=4, solver=solver,
+                             seed=j) for j in range(rows)])
+    steps = eng.ticks
+    assert len(out) == rows and steps > 0
+    tiles = eng.metrics.counter("serve_eps_tiles_total").value
+    slots = eng.metrics.counter("serve_eps_tile_rows_total").value
+    assert tiles == steps * tiles_per_step
+    assert slots == tiles * DLM.tile_rows(seq_len)
 
 
 def test_warm_engine_joins_without_compiling(diff_setup):

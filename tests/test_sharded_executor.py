@@ -174,6 +174,26 @@ def diff_setup():
     return params, cfg
 
 
+def test_engine_mesh_counts_every_devices_tiles(diff_setup):
+    """Under a request mesh each device loops over its own rows in tiles
+    of the bucket's width (8 rows at seq 32): ``serve_eps_tiles_total``
+    counts every device's trips, once per group step."""
+    from repro.diffusion import lm as DLM
+    from repro.serving.engine import DiffusionServeEngine, Request
+    params, cfg = diff_setup
+    n = min(jax.device_count(), 4)
+    eng = DiffusionServeEngine(params, cfg, mesh=_small_mesh())
+    out = eng.serve([Request(uid=i, seq_len=32, nfe=4, solver="tab2",
+                             seed=i) for i in range(3)])
+    assert len(out) == 3
+    rows = -(-3 // n) * n                    # rounded up to the data axis
+    per_step = n * -(-(rows // n) // DLM.tile_rows(32))
+    assert eng.metrics.counter("serve_eps_tiles_total").value \
+        == eng.ticks * per_step
+    assert eng.metrics.counter("serve_eps_tile_rows_total").value \
+        == eng.ticks * per_step * DLM.tile_rows(32)
+
+
 def test_engine_mesh_bitwise_equals_unsharded(diff_setup):
     """A small ('data',) mesh (1 device standalone; up to 4 when the suite
     runs under a forced host device count) exercises the whole sharded code

@@ -167,13 +167,13 @@ def _danube(n_layers=None):
         lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
 
 
-def _group(eng, cfg, solver):
-    """A stacked R-row group of ``solver`` at SEQ: (signature, plan, state)
-    as the engine's admission builds them, as shapes."""
+def _group(eng, cfg, solver, seq=SEQ):
+    """A stacked R-row group of ``solver`` at ``seq``: (signature, plan,
+    state) as the engine's admission builds them, as shapes."""
     plan = eng._plan(solver, 10, None)
     stacked = stack_plans([plan] * R)
     state = jax.eval_shape(lambda: DLM.init_sample_state(
-        cfg, stacked, DLM.request_keys(range(R)), seq_len=SEQ,
+        cfg, stacked, DLM.request_keys(range(R)), seq_len=seq,
         prior_std=1.0))
     return plan.signature, stacked, state
 
@@ -186,6 +186,23 @@ def test_served_danube_step_compiles(one_chip, kernels_compiled, solver):
     cfg, pshape = _danube()
     eng = DiffusionServeEngine(_shapes(pshape, one_chip), cfg)
     sig, plan, state = _group(eng, cfg, solver)
+    compiled, _ = eng._executor(sig, _shapes(plan, one_chip),
+                                _shapes(state, one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("seq", [32, 64])
+def test_served_danube_short_bucket_step_compiles(one_chip, kernels_compiled,
+                                                  seq):
+    """The same executor at the short buckets, whose eps tiles hold 8 and
+    4 rows (``lm.tile_rows``): R=8 rows of seq 32 or 64 compile with the
+    fused kernel and fit one v5e."""
+    cfg, pshape = _danube()
+    eng = DiffusionServeEngine(_shapes(pshape, one_chip), cfg)
+    sig, plan, state = _group(eng, cfg, "tab3", seq)
     compiled, _ = eng._executor(sig, _shapes(plan, one_chip),
                                 _shapes(state, one_chip))
     assert "tpu_custom_call" in compiled.as_text()
