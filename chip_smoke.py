@@ -145,7 +145,7 @@ def default_phase(check: Checks, seed: int, reduced: bool) -> None:
     print(f"param_bytes {sum(x.nbytes for x in jax.tree.leaves(params))}")
 
     eng = serve.build_diffusion_engine(args, cfg, params)
-    check("engine_fused_default", eng.fused)
+    check("engine_fused_default", eng._plan("tab3", NFE, None).fused)
     reqs = [Request(uid=i, seq_len=SEQ_LEN, nfe=NFE, solver=s, seed=i)
             for i, s in enumerate(SERVED)]
     solo = _drive(check, eng, reqs, vocab)

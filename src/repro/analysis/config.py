@@ -105,10 +105,9 @@ OWNERSHIP = {
         scheduler_entries=("tick", "serve", "submit", "cancel", "reset",
                            "busy"),
         config=("cfg", "sde", "schedule", "max_group", "steps_per_tick",
-                "aging_ticks", "compaction", "join", "seq_len_buckets",
-                "mesh", "_mesh_key", "_data_size", "_chunk_cap", "params",
-                "_params_exec", "enforce_deadlines", "retire", "metrics",
-                "tracer", "fused"),
+                "aging_ticks", "seq_len_buckets", "mesh", "_mesh_key",
+                "_data_size", "_chunk_cap", "params", "_params_exec",
+                "enforce_deadlines", "retire", "metrics", "tracer"),
         scheduler=("_plans", "_compiled", "_pending", "_active", "_arrivals",
                    "_boundary_results"),
         atomic=("_m_*", "_g_*", "_h_*"),

@@ -1,10 +1,9 @@
-"""Serving launcher: AR decode or DEIS diffusion sampling service.
-
-Three diffusion transports:
+"""Serving launcher: the DEIS diffusion sampling service over any
+``--arch`` eps-net, with three transports:
 
   sync (default)  -- drain a request list through the engine in-process:
     PYTHONPATH=src python -m repro.launch.serve --arch gemma_2b --reduced \
-        --mode diffusion --nfe 10 --solver tab3 --requests 8
+        --nfe 10 --solver tab3 --requests 8
 
   driver          -- asyncio demo over the ServeDriver: mixed-priority
                      ragged-NFE requests submitted concurrently via
@@ -19,10 +18,6 @@ Three diffusion transports:
                      by the final result line:
     PYTHONPATH=src python -m repro.launch.serve --arch gemma_2b --reduced \
         --transport http --port 8433
-
-AR mode is unchanged:
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma_2b --reduced \
-        --mode ar --requests 4 --max-new 16
 """
 from __future__ import annotations
 
@@ -42,7 +37,7 @@ from ..models import transformer as T
 from ..obs.export import NdjsonExporter, to_prometheus
 from ..obs.trace import Tracer
 from ..serving.driver import QueueFull, ServeDriver
-from ..serving.engine import ARServeEngine, DiffusionServeEngine, Request
+from ..serving.engine import DiffusionServeEngine, Request
 from ..training import checkpoint as CKPT
 
 
@@ -239,7 +234,6 @@ def make_parser() -> argparse.ArgumentParser:
     ap.add_argument("--seed", type=int, default=0,
                     help="PRNG seed of the randomly initialised params "
                          "(before any --ckpt-dir restore)")
-    ap.add_argument("--mode", choices=["ar", "diffusion"], default="diffusion")
     ap.add_argument("--transport", choices=["sync", "driver", "http"],
                     default="sync")
     ap.add_argument("--port", type=int, default=8433)
@@ -248,14 +242,8 @@ def make_parser() -> argparse.ArgumentParser:
     ap.add_argument("--nfe", type=int, default=10)
     ap.add_argument("--solver", default="tab3")
     ap.add_argument("--seq-len", type=int, default=32)
-    ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--steps-per-tick", type=int, default=None,
                     help="throttle: groups stepped per tick (enables EDF)")
-    ap.add_argument("--no-compaction", action="store_true")
-    ap.add_argument("--no-join", action="store_true",
-                    help="disable continuous admission (joining pending "
-                         "requests into in-flight groups of their bucket "
-                         "at every step boundary)")
     ap.add_argument("--seq-len-buckets", default=None,
                     help="comma-separated ascending edges (e.g. 32,64,128): "
                          "request seq_lens round up to a bucket edge so "
@@ -303,7 +291,7 @@ def load_model(args):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    cfg = cfg.with_(objective="diffusion" if args.mode == "diffusion" else "ar")
+    cfg = cfg.with_(objective="diffusion")
     # jitted, so each leaf is written once in its own dtype: eager init
     # holds every layer twice (per-layer leaves, then their stack)
     params = jax.jit(T.init_params, static_argnums=0)(
@@ -333,8 +321,6 @@ def build_diffusion_engine(args, cfg, params) -> DiffusionServeEngine:
         print(f"early exit on: {retire}")
     return DiffusionServeEngine(params, cfg,
                                 steps_per_tick=args.steps_per_tick,
-                                compaction=not args.no_compaction,
-                                join=not args.no_join,
                                 seq_len_buckets=buckets,
                                 mesh=mesh,
                                 enforce_deadlines=args.enforce_deadlines,
@@ -346,77 +332,67 @@ def main():
     enable_compile_cache()
     cfg, params = load_model(args)
 
-    if args.mode == "diffusion":
-        eng = build_diffusion_engine(args, cfg, params)
-        retire = eng.retire
-        if args.trace_annotate:
-            eng.tracer = Tracer(eng.metrics, annotate=True)
-        exporter = NdjsonExporter(args.metrics_ndjson,
-                                  extra={"arch": args.arch}) \
-            if args.metrics_ndjson else None
-        if args.transport == "http":
-            with ServeDriver(eng, max_pending=args.max_pending) as driver:
-                server = make_http_server(driver, args.port)
-                host, port = server.server_address
-                print(f"serving DEIS on http://{host}:{port}/v1/generate "
-                      "(POST JSON; GET /metrics for Prometheus text; "
-                      "Ctrl-C to stop)")
-                stop_snap = threading.Event()
-                if exporter is not None:
-                    def _snap_loop():
-                        while not stop_snap.wait(args.metrics_interval):
-                            exporter.write(eng.metrics)
-                    threading.Thread(target=_snap_loop, daemon=True,
-                                     name="metrics-ndjson").start()
-                try:
-                    server.serve_forever()
-                except KeyboardInterrupt:
-                    pass
-                finally:
-                    stop_snap.set()
-                    server.shutdown()
-                    if exporter is not None:
-                        exporter.write(eng.metrics)   # final snapshot
-                        exporter.close()
-            return
-        if args.transport == "driver":
-            with ServeDriver(eng, max_pending=args.max_pending) as driver:
-                results = asyncio.run(
-                    _driver_demo(driver, args.requests, args.seq_len))
-                print(f"served {len(results)} requests; "
-                      f"stats={driver.stats()}")
+    eng = build_diffusion_engine(args, cfg, params)
+    retire = eng.retire
+    if args.trace_annotate:
+        eng.tracer = Tracer(eng.metrics, annotate=True)
+    exporter = NdjsonExporter(args.metrics_ndjson,
+                              extra={"arch": args.arch}) \
+        if args.metrics_ndjson else None
+    if args.transport == "http":
+        with ServeDriver(eng, max_pending=args.max_pending) as driver:
+            server = make_http_server(driver, args.port)
+            host, port = server.server_address
+            print(f"serving DEIS on http://{host}:{port}/v1/generate "
+                  "(POST JSON; GET /metrics for Prometheus text; "
+                  "Ctrl-C to stop)")
+            stop_snap = threading.Event()
             if exporter is not None:
-                exporter.write(eng.metrics)
-                exporter.close()
-            return
-        reqs = [Request(uid=i, seq_len=args.seq_len, nfe=args.nfe,
-                        solver=args.solver, seed=i) for i in range(args.requests)]
-        results = eng.serve(
-            reqs, on_step=lambda e: print(
-                f"  step {e.k}/{e.n_steps} for uids {e.uids}"))
-        for r in results[:4]:
-            print(f"req {r.uid}: nfe={r.nfe} solve={r.latency_s:.2f}s "
-                  f"compile={r.compile_s:.2f}s early_exit={r.early_exit} "
-                  f"tokens[:10]={r.tokens[:10]}")
-        print(f"served {len(results)} requests")
-        if retire is not None:
-            m = eng.metrics
-            print(f"early exits: "
-                  f"{int(m.get('serve_early_exit_total').value)}/"
-                  f"{len(results)}, saved NFEs: "
-                  f"{int(m.get('serve_saved_nfe_total').value)}")
+                def _snap_loop():
+                    while not stop_snap.wait(args.metrics_interval):
+                        exporter.write(eng.metrics)
+                threading.Thread(target=_snap_loop, daemon=True,
+                                 name="metrics-ndjson").start()
+            try:
+                server.serve_forever()
+            except KeyboardInterrupt:
+                pass
+            finally:
+                stop_snap.set()
+                server.shutdown()
+                if exporter is not None:
+                    exporter.write(eng.metrics)   # final snapshot
+                    exporter.close()
+        return
+    if args.transport == "driver":
+        with ServeDriver(eng, max_pending=args.max_pending) as driver:
+            results = asyncio.run(
+                _driver_demo(driver, args.requests, args.seq_len))
+            print(f"served {len(results)} requests; "
+                  f"stats={driver.stats()}")
         if exporter is not None:
             exporter.write(eng.metrics)
             exporter.close()
-    else:
-        eng = ARServeEngine(params, cfg, max_len=args.seq_len + args.max_new)
-        rng = np.random.RandomState(0)
-        reqs = [Request(uid=i, prompt=rng.randint(0, cfg.vocab_size, 8),
-                        max_new_tokens=args.max_new) for i in range(args.requests)]
-        results = eng.serve(reqs)
-        for r in results[:4]:
-            print(f"req {r.uid}: latency={r.latency_s:.2f}s tokens={r.tokens[:10]}")
-        print(f"served {len(results)} requests")
+        return
+    reqs = [Request(uid=i, seq_len=args.seq_len, nfe=args.nfe,
+                    solver=args.solver, seed=i) for i in range(args.requests)]
+    results = eng.serve(
+        reqs, on_step=lambda e: print(
+            f"  step {e.k}/{e.n_steps} for uids {e.uids}"))
+    for r in results[:4]:
+        print(f"req {r.uid}: nfe={r.nfe} solve={r.latency_s:.2f}s "
+              f"compile={r.compile_s:.2f}s early_exit={r.early_exit} "
+              f"tokens[:10]={r.tokens[:10]}")
+    print(f"served {len(results)} requests")
+    if retire is not None:
+        m = eng.metrics
+        print(f"early exits: "
+              f"{int(m.get('serve_early_exit_total').value)}/"
+              f"{len(results)}, saved NFEs: "
+              f"{int(m.get('serve_saved_nfe_total').value)}")
+    if exporter is not None:
+        exporter.write(eng.metrics)
+        exporter.close()
 
 
 if __name__ == "__main__":

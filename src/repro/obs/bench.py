@@ -31,12 +31,12 @@ queue waits) ratchet at ``tol: 0`` -- any drift fails. Wall-clock timings
 are recorded with ``ratchet: false`` by default: they accumulate the
 trajectory without making CI flaky across machines; flip them on (with a
 generous tol) on a pinned benchmark host. A record always compares clean
-against itself, which is what CI's perf-smoke job asserts before ratcheting
-against the committed baseline.
+against itself. CI's lint job ratchets the static-analysis record
+(``BENCH_static.json``) against its committed baseline.
 
 CLI::
 
-    python -m repro.obs.bench show BENCH_serving.json
+    python -m repro.obs.bench show BENCH_static.json
     python -m repro.obs.bench compare BASELINE.json CURRENT.json [--tol 0.1]
 
 ``compare`` exits non-zero on any regression (the CI failure signal) and

@@ -1,9 +1,6 @@
-"""Batched serving engines.
-
-ARServeEngine      : classic prefill + KV-cache decode loop over a request
-                     queue (continuous slot-based batching).
-DiffusionServeEngine: the paper's workload as a *streaming continuous-batching*
-                     service over the pure ``step()`` executor.
+"""The DEIS sampling service: :class:`DiffusionServeEngine` runs the paper's
+workload as a *streaming continuous-batching* service over the pure
+``step()`` executor.
 
 Diffusion serving semantics
 ---------------------------
@@ -58,25 +55,24 @@ Completion, compaction & refill.  Rows of a ragged group finish at their
 OWN step count (``g.k - k0 == n_steps``; a joiner's ``k0`` is its admission
 tick): a finished row's Result is emitted from that very tick (its latency
 is the group's solve time accumulated since ITS admission), not when the
-whole group drains. With ``compaction=True`` (default) the group rebuilds
-at the next tick's admission boundary, before it steps again: freed rows
-are refilled with pending joiners, or the survivors are row-gathered
+whole group drains. The group rebuilds at the next tick's admission
+boundary, before it steps again: freed rows are refilled with pending
+joiners, or the survivors are row-gathered
 (:func:`repro.core.plan.take_rows` +
 :func:`repro.core.sampler.take_state_rows`) into a smaller
-``(signature, batch, seq_len)`` bucket and keep stepping there, instead of
-burning evals on retired rows. Both moves preserve bitwise per-request
-reproducibility because every per-row quantity -- coefficients, iterate,
-eps history, PRNG key chain -- moves whole. ``wasted_row_steps`` counts the
-steps executed on already-finished rows (zero under compaction -- joined
-slots and structural filler excluded; the no-compaction baseline pays one
-per dead row per tick).
+``(signature, batch, seq_len)`` bucket and keep stepping there -- a
+compaction is a join with no joiners. Both moves preserve bitwise
+per-request reproducibility because every per-row quantity --
+coefficients, iterate, eps history, PRNG key chain -- moves whole. So no
+retired request row ever steps: ``wasted_row_steps`` counts such steps
+and stays zero (structural filler excluded).
 
 Compile cache.  One jitted ``step`` is AOT-compiled per
 ``(plan.signature, batch, seq_len)`` and reused across groups, solver names
 and step indices (``k`` is traced as a PER-ROW vector, so the same
 executable serves uniform groups and post-join groups whose rows run at
-their own counts; pndm's warmup/tail split is a ``lax.cond``). Compaction looks its smaller batch up in the same cache, so a
-steady-state workload (e.g. the warm half of ``benchmarks/deis_serving``)
+their own counts; pndm's warmup/tail split is a ``lax.cond``). Compaction
+looks its smaller batch up in the same cache, so a steady-state workload
 runs with ZERO recompilation. The boundary pass's row moves -- the
 splice of joiners, the gather of survivors, the pick of finished rows to
 decode -- run the jitted device halves of ``join_rows`` / ``take_rows``
@@ -122,10 +118,8 @@ from ..core.plan import (SolverPlan, concat_plan_rows, gather_plan_rows,
                          stack_plans, take_rows)
 from ..core.sde import SDE, VPSDE
 from ..diffusion import lm as DLM
-from ..models import transformer as T
 from ..obs.metrics import MetricsRegistry
 from ..obs.trace import Tracer
-from ..training.steps import make_decode_step, make_prefill_step
 
 
 class DeadlineExceeded(RuntimeError):
@@ -153,7 +147,7 @@ class Cancelled(RuntimeError):
 
 @dataclasses.dataclass
 class Request:
-    """One serving request (AR or diffusion; diffusion fields listed last).
+    """One sampling request.
 
     ``priority`` (higher = more urgent) and ``deadline_s`` (latency budget in
     seconds, relative to submit time; ``None`` = best-effort) feed the
@@ -162,9 +156,7 @@ class Request:
     ``(solver, nfe, eta, seed, seq_len)``.
     """
     uid: int
-    prompt: np.ndarray | None = None       # AR: token prompt
-    max_new_tokens: int = 32
-    seq_len: int = 64                      # diffusion: sample length
+    seq_len: int = 64                      # sample length
     nfe: int = 10
     solver: str = "tab3"
     eta: float | None = None               # required iff solver == "ddim_eta"
@@ -240,57 +232,6 @@ class StepEvent:
                                      # group's plans carry embedded pairs --
                                      # entries are +inf until a row's first
                                      # genuine estimate)
-
-
-class ARServeEngine:
-    """Slot-based continuous batching: up to ``max_batch`` concurrent decodes."""
-
-    def __init__(self, params, cfg: ModelConfig, *, max_batch: int = 8,
-                 max_len: int = 512):
-        self.params, self.cfg = params, cfg
-        self.max_batch, self.max_len = max_batch, max_len
-        self._decode = jax.jit(make_decode_step(cfg))
-        self._prefill = jax.jit(make_prefill_step(cfg))
-
-    def serve(self, requests: list[Request], extras_fn=None) -> list[Result]:
-        """Run all requests to completion; returns Results (greedy decode)."""
-        cfg = self.cfg
-        results: list[Result] = []
-        queue = list(requests)
-        # static single-sequence path batched over slots sequentially -- a
-        # deliberately simple, correct reference loop (throughput benchmarks
-        # jit the batched decode path directly).
-        for req in queue:
-            # perf_counter, NOT time.time(): the diffusion engine times with
-            # the monotonic perf_counter, and mixing clock domains lets a
-            # wall-clock step (NTP, suspend) yield negative/garbage latency.
-            t0 = time.perf_counter()
-            extras = extras_fn(req) if extras_fn else {}
-            prompt = jnp.asarray(req.prompt)[None]
-            batch = {"tokens": prompt, **extras}
-            logits, cache = self._prefill(self.params, batch)
-            # grow cache to max_len
-            def grow(leaf):
-                if leaf.ndim >= 3 and leaf.shape[2] == prompt.shape[1] and not (
-                        cfg.sliding_window and leaf.shape[2] == cfg.sliding_window):
-                    pad = [(0, 0)] * leaf.ndim
-                    pad[2] = (0, self.max_len - leaf.shape[2])
-                    return jnp.pad(leaf, pad)
-                return leaf
-            cache = dict(cache)
-            cache["blocks"] = jax.tree.map(grow, cache["blocks"])
-            tok = jnp.argmax(logits, -1)[:, None]
-            out_tokens = [int(tok[0, 0])]
-            pos = prompt.shape[1]
-            for _ in range(req.max_new_tokens - 1):
-                logits, cache = self._decode(self.params, cache, tok,
-                                             jnp.int32(pos))
-                tok = jnp.argmax(logits, -1)[:, None]
-                out_tokens.append(int(tok[0, 0]))
-                pos += 1
-            results.append(Result(req.uid, np.asarray(out_tokens),
-                                  time.perf_counter() - t0))
-        return results
 
 
 # err histogram edges: local-error estimates are small dimensionless
@@ -401,25 +342,20 @@ class DiffusionServeEngine:
     def __init__(self, params, cfg: ModelConfig, sde: Optional[SDE] = None,
                  schedule: str = "quadratic", max_group: int = 8,
                  steps_per_tick: int | None = None, aging_ticks: int = 8,
-                 compaction: bool = True, join: bool = True,
                  seq_len_buckets=None, mesh=None,
                  enforce_deadlines: bool = False,
                  retire: RetirePolicy | None = None,
                  metrics: MetricsRegistry | None = None,
-                 tracer: Tracer | None = None,
-                 fused: bool = True):
-        """``steps_per_tick``: groups advanced per tick (None = all active,
-        the PR-2 behavior; an int enables true EDF selection).
-        ``aging_ticks``: skipped ticks per +1 effective-priority boost
-        (starvation aging). ``compaction``: retire finished rows mid-flight
-        and re-pack survivors into a smaller cached batch bucket.
+                 tracer: Tracer | None = None):
+        """``steps_per_tick``: groups advanced per tick (None = all active;
+        an int enables true EDF selection). ``aging_ticks``: skipped ticks
+        per +1 effective-priority boost (starvation aging).
 
-        ``join``: continuous admission -- at every step boundary, pending
-        requests are spliced into an in-flight group of their bucket and
-        priority that has room (retired rows become slots) instead of
-        forming a fresh group, under the same priority/EDF ordering as
-        admission. Requires ``compaction`` (boundaries are where groups
-        rebuild); with ``compaction=False`` the flag is inert.
+        Every ``ab``-method plan steps through the fused Pallas megakernel
+        (psi/C combination + noise add + error-pair estimate in ONE kernel:
+        one HBM round-trip instead of r+3). Stacked fused rows are bitwise
+        identical to solo fused rows (the row-block grid axis computes each
+        row's blocks independently).
 
         ``seq_len_buckets``: ascending edge lengths; a request's seq_len
         rounds UP to the first edge that fits, the solve runs at the bucket
@@ -480,27 +416,16 @@ class DiffusionServeEngine:
         :class:`~repro.obs.trace.Tracer` for host-side span timing of
         ticks/steps/compiles/boundary work; ``None`` builds one over the
         same registry. Instrumentation is host-side only -- nothing here
-        syncs the device or touches the jitted step.
-
-        ``fused``: route every ``ab``-method plan through the fused
-        Pallas megakernel step (psi/C combination + noise add + error-pair
-        estimate in ONE kernel -- one HBM round-trip instead of r+3).
-        On by default. Off only changes WHICH executor computes a step,
-        never row content across group compositions: stacked fused rows are
-        bitwise identical to solo fused rows (the row-block grid axis
-        computes each row's blocks independently)."""
+        syncs the device or touches the jitted step."""
         assert cfg.objective == "diffusion"
         self.params, self.cfg = params, cfg
         self.sde = sde or VPSDE()
         self.schedule = schedule
-        self.fused = bool(fused)
         self.max_group = max_group
         # clamp: 0/negative would make tick() select nothing and busy-loop
         self.steps_per_tick = None if steps_per_tick is None \
             else max(1, steps_per_tick)
         self.aging_ticks = max(1, aging_ticks)
-        self.compaction = compaction
-        self.join = join
         if seq_len_buckets is not None:
             edges = tuple(int(e) for e in seq_len_buckets)
             if not edges or any(e < 1 for e in edges) or \
@@ -555,10 +480,10 @@ class DiffusionServeEngine:
         # finished list
         self._boundary_results: list[Result] = []
 
-        # ---- observability: every scheduler metric lives in the registry;
-        # the legacy int counters (ticks/wasted_row_steps/joined_requests)
-        # are back-compat properties over it. Metric objects are resolved
-        # ONCE here -- the tick loop touches attributes, never the registry.
+        # ---- observability: every scheduler metric lives in the registry
+        # (ticks/wasted_row_steps/joined_requests are read-only views over
+        # it). Metric objects are resolved ONCE here -- the tick loop
+        # touches attributes, never the registry.
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.tracer = tracer if tracer is not None else Tracer(self.metrics)
         reg = self.metrics
@@ -566,7 +491,8 @@ class DiffusionServeEngine:
             "serve_ticks_total", "scheduler ticks executed")
         self._m_wasted = reg.counter(
             "serve_wasted_row_steps_total",
-            "steps burned on already-finished request rows")
+            "steps run on already-finished request rows: the boundary "
+            "rebuild keeps this at 0")
         self._m_joined = reg.counter(
             "serve_joined_requests_total",
             "requests admitted by joining an in-flight group")
@@ -627,35 +553,21 @@ class DiffusionServeEngine:
         self._h_tick = reg.histogram(
             "serve_tick_seconds", "one full scheduler tick")
 
-    # ---- legacy int counters: back-compat views over the registry. The
-    # setters exist because benchmarks/tests re-zero them between the cold
-    # (compile) pass and the warm measured pass.
+    # ---- int views over the registry
     @property
     def ticks(self) -> int:
         """Scheduler ticks executed (metric)."""
         return int(self._m_ticks.value)
 
-    @ticks.setter
-    def ticks(self, v: int) -> None:
-        self._m_ticks.reset(v)
-
     @property
     def wasted_row_steps(self) -> int:
-        """Steps burned on already-finished rows (metric)."""
+        """Steps run on already-finished rows (metric; always 0)."""
         return int(self._m_wasted.value)
-
-    @wasted_row_steps.setter
-    def wasted_row_steps(self, v: int) -> None:
-        self._m_wasted.reset(v)
 
     @property
     def joined_requests(self) -> int:
         """Requests admitted by joining an in-flight group (metric)."""
         return int(self._m_joined.value)
-
-    @joined_requests.setter
-    def joined_requests(self, v: int) -> None:
-        self._m_joined.reset(v)
 
     # ------------------------------------------------------------- plans
     def _plan(self, solver: str, nfe: int, eta: float | None) -> SolverPlan:
@@ -679,7 +591,7 @@ class DiffusionServeEngine:
             # of a known (solver, nfe, eta) never re-runs the float64
             # host precompute
             plan = cached_make_plan(solver, self.sde, ts, **kw)
-            if self.fused and plan.method == "ab":
+            if plan.method == "ab":
                 plan = dataclasses.replace(plan, fused=True)
             self._plans[key_] = plan
         return self._plans[key_]
@@ -1016,13 +928,13 @@ class DiffusionServeEngine:
         Two phases, both ordered by the same urgency key (priority desc,
         deadline asc):
 
-        1. *Boundary pass* (``compaction`` on): every in-flight group is
-           offered the pending requests of its bucket and priority whose
-           grids fit its horizon, and they JOIN it up to ``max_group`` live
-           rows (retired rows become slots; ``join`` on). A group carrying
-           retired/filler rows that nothing refills compacts down to its
-           survivors. Groups are visited in ``_select``'s urgency order, so
-           the most urgent in-flight work gets the most urgent joiners.
+        1. *Boundary pass*: every in-flight group is offered the pending
+           requests of its bucket and priority whose grids fit its horizon,
+           and they JOIN it up to ``max_group`` live rows (retired rows
+           become slots). A group carrying retired rows that nothing refills
+           compacts down to its survivors (:meth:`_rebuild`). Groups are
+           visited in ``_select``'s urgency order, so the most urgent
+           in-flight work gets the most urgent joiners.
         2. *Fresh groups*: remaining pending requests bucket by
            ``(plan.family, bucketed seq_len)`` -- any mix of solver names
            AND NFE budgets whose plans pad+stack is one solve (ragged
@@ -1059,30 +971,10 @@ class DiffusionServeEngine:
         for items in buckets.values():
             items.sort(key=lambda it: (-it.req.priority,
                                        self._abs_deadline(it.req, it.t_sub)))
-        if self.compaction:
-            for g in sorted(self._active, key=self._group_key):
-                take = (self._joiners(g, buckets.get(g.bucket))
-                        if self.join else [])
-                if take:
-                    with self.tracer.span("join"):
-                        self._join_group(g, take, now)
-                    continue
-                if not any(r.done for r in g.rows):
-                    continue
-                live = [i for i, r in enumerate(g.rows) if not r.done]
-                keep = self._compact_target(g, live)
-                if keep is not None:
-                    with self.tracer.span("compact"):
-                        self._compact(g, keep)
-                else:
-                    # the group already sits at the smallest placeable
-                    # multiple of the data axis (mesh only: unsharded groups
-                    # always shrink): its retired rows are structurally
-                    # required filler -- same status as rows retained by a
-                    # compaction -- not waste (and open join slots)
-                    for r in g.rows:
-                        if r.done:
-                            r.pad = True
+        for g in sorted(self._active, key=self._group_key):
+            take = self._joiners(g, buckets.get(g.bucket))
+            if take or any(r.done for r in g.rows):
+                self._rebuild(g, take, now)
         for (fam, s_len), items in buckets.items():
             for i in range(0, len(items), self._chunk_cap):
                 with self.tracer.span("form"):
@@ -1164,62 +1056,6 @@ class DiffusionServeEngine:
         cands[:] = rest
         return take
 
-    def _join_group(self, g: _Group, take: list, now: float) -> None:
-        """Splice the pending requests ``take`` (see :meth:`_joiners`) into
-        ``g`` at a step boundary. The rebuilt batch
-        keeps the surviving rows in their original relative order, each
-        carried whole and bitwise-unmoved (``take_rows`` of the survivors
-        when rows retired, then ``join_rows`` appending the padded
-        joiners), rounds up to a
-        data-axis multiple reusing retired rows as slots before allocating
-        inert filler, and stays within ``max_group``. Joiner
-        rows record ``k0 = g.k`` (their steps count from THIS tick) and
-        ``solve_s0`` (their latency excludes the group's past)."""
-        live = [i for i, r in enumerate(g.rows) if not r.done]
-        keep, n_inert = self._round_keep(g, live, len(take))
-        if keep != list(range(len(g.rows))):
-            # the intermediate gather may not be a data-axis multiple (e.g.
-            # 8 rows -> 4 survivors before 4 joiners splice back to 8), so
-            # it stays unplaced; only the FINAL spliced batch -- always a
-            # multiple -- is placed (after join_rows/join_state_rows below)
-            g.plan = take_rows(g.plan, keep)
-            g.state = SAMPLER.take_state_rows(g.state, keep)
-            g.rows = [g.rows[i] for i in keep]
-        for r in g.rows:
-            if r.done:          # retained retired row: structural filler
-                r.pad = True
-        padded = [pad_plan(p.plan, g.plan.n_steps) for p in take]
-        seeds = [p.req.seed for p in take]
-        new_rows = [_Row(req=p.req, n_steps=p.plan.n_steps, nfe=p.plan.nfe,
-                         deadline=self._abs_deadline(p.req, p.t_sub),
-                         k0=g.k, solve_s0=g.solve_s, wait_s=now - p.t_sub)
-                    for p in take]
-        if n_inert:
-            filler = inert_row(padded[0])
-            padded += [filler] * n_inert
-            seeds += [0] * n_inert
-            new_rows += [_Row(req=None, n_steps=0, nfe=0, deadline=math.inf,
-                              done=True, pad=True, k0=g.k)
-                         for _ in range(n_inert)]
-        with self.tracer.span("prior"):
-            keys = DLM.request_keys(seeds)
-            add_state = DLM.init_sample_state(
-                self.cfg, stack_plans(padded), keys, seq_len=g.seq_len,
-                prior_std=self.sde.prior_std(),
-                valid_lens=[p.req.seq_len for p in take]
-                + [g.seq_len] * n_inert)
-        g.plan, g.state = self._place(
-            join_rows(g.plan, padded),
-            SAMPLER.join_state_rows(g.state, add_state))
-        g.rows += new_rows
-        live_rows = [r for r in g.rows if not r.done]
-        g.n_steps = max(r.k0 + r.n_steps for r in live_rows)
-        g.priority = max(r.req.priority for r in live_rows)
-        g.deadline = min(r.deadline for r in live_rows)
-        g.fn, compile_s = self._executor(g.sig, g.plan, g.state)
-        g.compile_s += compile_s
-        self._m_joined.inc(len(take))
-
     def _select(self) -> tuple[list[_Group], list[_Group]]:
         """Order active groups by urgency; return (stepped, skipped).
 
@@ -1253,48 +1089,76 @@ class DiffusionServeEngine:
         return (sorted(live + reuse),
                 target - len(live) - n_new - len(reuse))
 
-    def _compact_target(self, g: _Group, live: list[int]) -> list[int] | None:
-        """Row indices to KEEP when compacting ``g``, or None to skip.
+    def _rebuild(self, g: _Group, take: list, now: float) -> None:
+        """Rebuild ``g`` at a step boundary: splice in the pending requests
+        ``take`` (see :meth:`_joiners`), or with none, compact it down to
+        its live rows -- a compaction is a join with no joiners.
 
-        Unsharded: keep exactly the live rows (compact whenever any row
-        retired). Under a mesh the kept count must stay a multiple of the
-        data-axis size (:meth:`_round_keep`); when the rounded target
-        equals the current batch there is nothing to shrink and compaction
-        is skipped (no resharding, no recompile, no churn).
-        """
-        keep, _ = self._round_keep(g, live, 0)
-        if len(keep) >= len(g.rows):
-            return None
-        return keep
-
-    def _compact(self, g: _Group, keep: list[int]) -> None:
-        """Re-pack kept rows into a smaller (sig, batch, seq_len) bucket.
-
-        Gathers plan rows and state rows whole (coefficients, iterate, eps
-        history, per-request key chains), so the surviving requests' samples
-        are bit-identical to an uncompacted solve; only the executor changes,
-        to the cached one for the smaller batch (compiled on first need,
-        charged to this group's ``compile_s``). Under a mesh the gathers are
-        sharding-preserving (committed straight back to the request-axis
-        ``NamedSharding``), so mid-flight shrink never reshards or
-        recompiles. Group urgency is recomputed from the LIVE survivors so a
-        retired urgent row's priority/deadline does not keep preempting
-        other groups on behalf of best-effort leftovers."""
-        self._m_compactions.inc()
-        g.plan, g.state = self._place(take_rows(g.plan, keep),
-                                      SAMPLER.take_state_rows(g.state, keep))
-        g.rows = [g.rows[i] for i in keep]
-        live = []
+        The rebuilt batch keeps the surviving rows in their original
+        relative order, each carried whole and bitwise-unmoved (coefficients,
+        iterate, eps history, per-request key chains): ``take_rows`` of the
+        kept rows when the row set changes, then ``join_rows`` appending the
+        padded joiners. Under a mesh it rounds up to a data-axis multiple
+        (:meth:`_round_keep`), reusing retired rows as structural filler
+        before allocating inert rows, and stays within ``max_group``; only
+        the final batch is placed (an intermediate gather need not be a
+        multiple). Joiner rows record ``k0 = g.k`` (their steps count from
+        THIS tick) and ``solve_s0`` (their latency excludes the group's
+        past). The executor changes to the cached one of the new batch
+        (compiled on first need, charged to ``g.compile_s``), and group
+        urgency is recomputed from the LIVE rows, so a retired urgent row's
+        priority/deadline stops preempting other groups on behalf of
+        best-effort leftovers."""
+        live = [i for i, r in enumerate(g.rows) if not r.done]
+        keep, n_inert = self._round_keep(g, live, len(take))
+        # a retired row the rebuild keeps is structural filler: not waste,
+        # and an open join slot
         for r in g.rows:
-            if r.done:
-                r.pad = True        # retained retired row: structural filler
-            else:
-                live.append(r)
-        g.n_steps = max(r.k0 + r.n_steps for r in live)
-        g.priority = max(r.req.priority for r in live)
-        g.deadline = min(r.deadline for r in live)
-        g.fn, compile_s = self._executor(g.sig, g.plan, g.state)
-        g.compile_s += compile_s
+            r.pad = r.pad or r.done
+        if not take and len(keep) == len(g.rows):
+            # nothing joins and nothing shrinks: the group already sits at
+            # the smallest placeable multiple of the data axis (mesh only:
+            # unsharded groups always shrink)
+            return
+        with self.tracer.span("join" if take else "compact"):
+            if keep != list(range(len(g.rows))):
+                g.plan = take_rows(g.plan, keep)
+                g.state = SAMPLER.take_state_rows(g.state, keep)
+                g.rows = [g.rows[i] for i in keep]
+            if take:
+                padded = [pad_plan(p.plan, g.plan.n_steps) for p in take]
+                seeds = [p.req.seed for p in take]
+                g.rows += [_Row(req=p.req, n_steps=p.plan.n_steps,
+                                nfe=p.plan.nfe,
+                                deadline=self._abs_deadline(p.req, p.t_sub),
+                                k0=g.k, solve_s0=g.solve_s,
+                                wait_s=now - p.t_sub) for p in take]
+                if n_inert:
+                    padded += [inert_row(padded[0])] * n_inert
+                    seeds += [0] * n_inert
+                    g.rows += [_Row(req=None, n_steps=0, nfe=0,
+                                    deadline=math.inf, done=True, pad=True,
+                                    k0=g.k) for _ in range(n_inert)]
+                with self.tracer.span("prior"):
+                    add_state = DLM.init_sample_state(
+                        self.cfg, stack_plans(padded),
+                        DLM.request_keys(seeds), seq_len=g.seq_len,
+                        prior_std=self.sde.prior_std(),
+                        valid_lens=[p.req.seq_len for p in take]
+                        + [g.seq_len] * n_inert)
+                g.plan = join_rows(g.plan, padded)
+                g.state = SAMPLER.join_state_rows(g.state, add_state)
+            g.plan, g.state = self._place(g.plan, g.state)
+            live_rows = [r for r in g.rows if not r.done]
+            g.n_steps = max(r.k0 + r.n_steps for r in live_rows)
+            g.priority = max(r.req.priority for r in live_rows)
+            g.deadline = min(r.deadline for r in live_rows)
+            g.fn, compile_s = self._executor(g.sig, g.plan, g.state)
+            g.compile_s += compile_s
+        if take:
+            self._m_joined.inc(len(take))
+        else:
+            self._m_compactions.inc()
 
     @property
     def busy(self) -> bool:
@@ -1364,10 +1228,9 @@ class DiffusionServeEngine:
                 g.skipped = 0
                 # structural filler rows (pad) are free capacity in a
                 # data-parallel step, not waste; only retired REQUEST rows
-                # that keep stepping count. With compaction on, the
-                # admission-time boundary pass has already joined over /
-                # compacted away / pad-marked every retired row, so this
-                # stays zero.
+                # that keep stepping count. The admission-time boundary
+                # pass has already joined over / compacted away / pad-marked
+                # every retired row, so this stays zero.
                 self._m_wasted.inc(sum(
                     r.done and not r.pad for r in g.rows))
                 ks = [g.k - r.k0 for r in g.rows]

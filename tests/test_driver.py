@@ -238,8 +238,8 @@ def test_compile_cache_dir_prefers_env_then_checkout(monkeypatch, tmp_path):
 
 def test_cli_builds_the_engine_its_options_describe():
     """The serving CLI's constructors (shared with ``chip_smoke.py``):
-    ``--seed`` seeds the params, the engine is fused by default and takes
-    the early-exit policy from the options."""
+    ``--seed`` seeds the params, the engine compiles its ``ab`` executors
+    fused and takes the early-exit policy from the options."""
     from repro.launch import serve
     argv = ["--arch", "h2o_danube_3_4b", "--reduced"]
     parse = serve.make_parser().parse_args
@@ -249,5 +249,8 @@ def test_cli_builds_the_engine_its_options_describe():
     assert cfg.objective == "diffusion"
     assert not np.array_equal(params["eps_head"], params0["eps_head"])
     eng = serve.build_diffusion_engine(args, cfg, params)
-    assert eng.fused and eng.mesh is None
+    assert eng.mesh is None
     assert eng.retire is not None and eng.retire.tol == 1e-3
+    eng.serve([Request(uid=0, seq_len=8, nfe=2, solver="tab3", seed=0)])
+    sigs = [key[0] for key in eng._compiled]
+    assert sigs and all(sig[0] == "ab" and sig[2] for sig in sigs)

@@ -1,7 +1,6 @@
-"""Serving engines: AR generation against step-by-step reference; DEIS
-diffusion service streaming continuous-batching semantics (per-request
-reproducibility, step-boundary admission, compile/solve time split, NFE
-budget accounting, per-step callbacks)."""
+"""The DEIS diffusion service's streaming continuous-batching semantics:
+per-request reproducibility, step-boundary admission, compile/solve time
+split, NFE budget accounting, per-step callbacks."""
 import dataclasses
 
 import jax
@@ -12,29 +11,7 @@ import pytest
 from repro.configs.base import get_config
 from repro.diffusion import lm as DLM
 from repro.models import transformer as T
-from repro.serving.engine import ARServeEngine, DiffusionServeEngine, Request
-
-
-def test_ar_engine_matches_manual_greedy():
-    cfg = get_config("gemma_2b").reduced().with_(objective="ar")
-    params = T.init_params(cfg, jax.random.PRNGKey(0))
-    prompt = np.array([1, 2, 3, 4, 5, 6, 7, 8])
-    eng = ARServeEngine(params, cfg, max_len=32)
-    res = eng.serve([Request(uid=0, prompt=prompt, max_new_tokens=6)])
-    # one monotonic clock domain (perf_counter): latency can never go
-    # negative, even across a wall-clock step
-    assert res[0].latency_s >= 0.0
-    got = res[0].tokens
-
-    # manual greedy via repeated FULL forwards (no cache) -- ground truth
-    toks = list(prompt)
-    want = []
-    for _ in range(6):
-        out = T.forward(params, cfg, tokens=jnp.asarray(toks)[None], mode="train")
-        nxt = int(jnp.argmax(out["logits"][0, -1]))
-        want.append(nxt)
-        toks.append(nxt)
-    np.testing.assert_array_equal(got, np.array(want))
+from repro.serving.engine import DiffusionServeEngine, Request
 
 
 def test_diffusion_engine_batches_same_shape_requests():
@@ -251,7 +228,7 @@ def test_ragged_compaction_bitwise_vs_solo(diff_setup):
     untouched, and compaction row-gathers coefficients, state and key chains
     whole. The shrinking batches land in the shared executor cache."""
     params, cfg = diff_setup
-    eng = DiffusionServeEngine(params, cfg, compaction=True)
+    eng = DiffusionServeEngine(params, cfg)
     res = {r.uid: r for r in eng.serve(_ragged_reqs())}
     assert len(res) == 4
     assert eng.wasted_row_steps == 0           # compaction: no dead-row steps
@@ -268,35 +245,36 @@ def test_ragged_compaction_bitwise_vs_solo(diff_setup):
 
 
 def test_compaction_reduces_wasted_row_steps(diff_setup):
-    """Without compaction a ragged group burns one step per retired row per
-    tick (here: 6 + 3 + 3 = 12); with compaction, zero. Samples must be
-    bitwise identical either way."""
+    """A ragged group compacts as its short rows retire, so no retired row
+    ever steps (a group that kept them would burn 6 + 3 + 3 = 12 dead row
+    steps here), and every sample is bitwise the request's solo serve."""
     params, cfg = diff_setup
-    off = DiffusionServeEngine(params, cfg, compaction=False)
-    res_off = {r.uid: r.tokens for r in off.serve(_ragged_reqs())}
-    assert off.wasted_row_steps == 12
-    on = DiffusionServeEngine(params, cfg, compaction=True)
-    res_on = {r.uid: r.tokens for r in on.serve(_ragged_reqs())}
-    assert on.wasted_row_steps == 0
-    for uid in res_off:
-        np.testing.assert_array_equal(res_off[uid], res_on[uid])
+    eng = DiffusionServeEngine(params, cfg)
+    res = {r.uid: r.tokens for r in eng.serve(_ragged_reqs())}
+    assert eng.metrics.get("serve_compactions_total").value >= 1
+    assert eng.wasted_row_steps == 0
+    solo = DiffusionServeEngine(params, cfg)
+    for q in _ragged_reqs():
+        np.testing.assert_array_equal(solo.serve([q])[0].tokens,
+                                      res[q.uid])
 
 
 def test_deadline_request_preempts_older_work(diff_setup):
     """EDF under a throttled scheduler (steps_per_tick=1): a deadline-tight
     request submitted AFTER an in-flight best-effort group is stepped ahead
     of it every tick until it completes -- and the old work still drains.
-    join=False keeps B in a group of its own; with joins on, B (same bucket
-    and priority) rides in A's group, which its deadline then leads."""
+    At seq 32, B is in another bucket than A and forms a group of its own;
+    at seq 16 (same bucket and priority) B rides in A's group, which its
+    deadline then leads."""
     params, cfg = diff_setup
     for join in (False, True):
         eng = DiffusionServeEngine(params, cfg, steps_per_tick=1,
-                                   aging_ticks=1000, join=join)
+                                   aging_ticks=1000)
         events = []
         eng.submit(Request(uid=0, seq_len=16, nfe=6, solver="tab1", seed=0))
         done = eng.tick(on_step=events.append)          # A in flight, k=1
-        eng.submit(Request(uid=1, seq_len=16, nfe=3, solver="tab1", seed=1,
-                           deadline_s=0.05))
+        eng.submit(Request(uid=1, seq_len=16 if join else 32, nfe=3,
+                           solver="tab1", seed=1, deadline_s=0.05))
         while eng.busy:
             done += eng.tick(on_step=events.append)
         if join:
@@ -314,12 +292,11 @@ def test_compaction_recomputes_group_urgency(diff_setup):
     """When the urgent row of a ragged group retires, the surviving
     best-effort rows must NOT inherit its priority/deadline: a mid-priority
     newcomer preempts the compacted leftovers (no priority inversion).
-    join=False isolates the compaction path -- with joins on, the newcomer
-    would be spliced into the leftover group instead (covered by the join
-    tests below)."""
+    The newcomer's priority (1) differs from the leftover's (0), so it
+    forms a group of its own instead of joining the leftover's."""
     params, cfg = diff_setup
     eng = DiffusionServeEngine(params, cfg, steps_per_tick=1,
-                               aging_ticks=1000, join=False)
+                               aging_ticks=1000)
     eng.submit(Request(uid=0, seq_len=16, nfe=3, solver="ddim", seed=0,
                        priority=2, deadline_s=0.05))
     eng.submit(Request(uid=1, seq_len=16, nfe=9, solver="ddim", seed=1))
@@ -334,6 +311,14 @@ def test_compaction_recomputes_group_urgency(diff_setup):
     # the newcomer ran ahead of the leftover best-effort row every tick
     assert [e.uids for e in events[3:6]] == [(2,), (2,), (2,)]
     assert [r.uid for r in done] == [0, 2, 1]
+
+
+@pytest.mark.parametrize("switch", ["compaction", "join", "fused"])
+def test_engine_takes_no_mode_switch(switch):
+    """Joins, compaction and the fused AB kernel are how the engine always
+    serves: it takes no switch to turn one off."""
+    with pytest.raises(TypeError):
+        DiffusionServeEngine(None, None, **{switch: False})
 
 
 def test_engine_rejects_invalid_shapes_at_submit(diff_setup):

@@ -115,3 +115,43 @@ def test_loss_decreases_on_synthetic_corpus(objective):
         params, opt_state, m = step(params, opt_state, batch, sub)
         losses.append(float(m["loss"]))
     assert np.mean(losses[-5:]) < np.mean(losses[:5]), losses
+
+
+def test_kv_cache_greedy_matches_full_forward_greedy():
+    """The KV-cache prefill and decode steps: greedy decoding through a
+    prefilled cache (padded here to the decode length) gives the tokens of
+    greedy decoding by repeated full forwards with no cache."""
+    from repro.training.steps import make_decode_step, make_prefill_step
+    cfg = get_config("gemma_2b").reduced().with_(objective="ar")
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    prompt = np.array([1, 2, 3, 4, 5, 6, 7, 8])
+    n_new, max_len = 6, 32
+    prefill = jax.jit(make_prefill_step(cfg))
+    decode = jax.jit(make_decode_step(cfg))
+    logits, cache = prefill(params, {"tokens": jnp.asarray(prompt)[None]})
+
+    def grow(leaf):         # the cache's sequence axis, padded to max_len
+        if leaf.ndim >= 3 and leaf.shape[2] == len(prompt) and not (
+                cfg.sliding_window and leaf.shape[2] == cfg.sliding_window):
+            pad = [(0, 0)] * leaf.ndim
+            pad[2] = (0, max_len - leaf.shape[2])
+            return jnp.pad(leaf, pad)
+        return leaf
+    cache = dict(cache, blocks=jax.tree.map(grow, cache["blocks"]))
+    tok = jnp.argmax(logits, -1)[:, None]
+    got = [int(tok[0, 0])]
+    for pos in range(len(prompt), len(prompt) + n_new - 1):
+        logits, cache = decode(params, cache, tok, jnp.int32(pos))
+        tok = jnp.argmax(logits, -1)[:, None]
+        got.append(int(tok[0, 0]))
+
+    # greedy via repeated FULL forwards (no cache) -- ground truth
+    toks = list(prompt)
+    want = []
+    for _ in range(n_new):
+        out = T.forward(params, cfg, tokens=jnp.asarray(toks)[None],
+                        mode="train")
+        nxt = int(jnp.argmax(out["logits"][0, -1]))
+        want.append(nxt)
+        toks.append(nxt)
+    np.testing.assert_array_equal(np.asarray(got), np.array(want))
